@@ -115,15 +115,12 @@ def _donation_section(eval_every: int, rounds: int):
 
     out = {"backend": jax.default_backend(),
            "donation_supported": jax.default_backend() != "cpu"}
-    S, T = rounds // eval_every, eval_every
     model, fed, cfg = _build(eval_every, rounds)   # one federation, two
     params = model.init(jax.random.PRNGKey(cfg.seed + 1))   # compiles
     for label, donate in (("donate_on", True), ("donate_off", False)):
         engine = RoundEngine(model, fed, cfg, donate=donate)
-        _, subs = engine._segment_keys(jax.random.PRNGKey(0), rounds)
-        lowered = engine._training.lower(
-            params, subs.reshape((S, T) + subs.shape[1:]),
-            jnp.zeros((S, T), jnp.float32), engine.default_scenario)
+        lowered = engine.lower_training(
+            params, jax.random.PRNGKey(0), jnp.zeros((rounds,), jnp.float32))
         stats = lowered.compile().memory_analysis()
         out[label] = {
             "temp_mb": round(stats.temp_size_in_bytes / 1e6, 2),

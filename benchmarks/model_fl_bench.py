@@ -106,8 +106,11 @@ def _segment_temp_mb(eng, params, rounds: int) -> float:
     return comp.memory_analysis().temp_size_in_bytes / 1e6
 
 
-def _timed_run(eng, params, rounds: int):
-    """(metrics dict of np arrays, rounds/sec) for a short training."""
+def _timed_run(eng, rounds: int):
+    """(metrics dict of np arrays, rounds/sec) for a short training.
+    Each run inits its own params: off the CPU the engine donates the
+    carry, so params handed to one run are gone after it."""
+    params = eng.model.init(jax.random.PRNGKey(1))
     lrs = jnp.full((rounds,), 3e-2, jnp.float32)
     t0 = time.time()
     _p, _k, metrics, _er = eng.run_training(
@@ -149,26 +152,24 @@ def run(smoke: bool = False):
     if have_mesh:
         eng_s = _engine(model, _cfg(n_clients=2, rounds=rounds),
                         mesh=make_host_mesh(data=2, model=4))
-        m_s, sharded_rps = _timed_run(eng_s, params, rounds)
+        m_s, sharded_rps = _timed_run(eng_s, rounds)
         sharded_ok = all(np.isfinite(v).all() for v in m_s.values())
         emit("model_fl/sharded_rounds_per_sec", 1e6 / max(sharded_rps, 1e-9),
              f"{sharded_rps:.4f}")
-    m_f, flat_rps = _timed_run(eng_flat, params, rounds)
+    m_f, flat_rps = _timed_run(eng_flat, rounds)
     flat_ok = all(np.isfinite(v).all() for v in m_f.values())
     emit("model_fl/unsharded_rounds_per_sec", 1e6 / max(flat_rps, 1e-9),
          f"{flat_rps:.4f}")
 
     # ---- model-axis=1 bitwise gate (scale-free: tiny model) ---------
     gate_model = zoo_model(TINY_MODEL, seq_len=16)
-    gate_params = gate_model.init(jax.random.PRNGKey(1))
     gcfg = _cfg(n_clients=4, rounds=4)
-    hist_meshless, _ = _timed_run(_engine(gate_model, gcfg),
-                                  gate_params, gcfg.rounds)
+    hist_meshless, _ = _timed_run(_engine(gate_model, gcfg), gcfg.rounds)
     bitwise = True
     if have_mesh:
         hist_m1, _ = _timed_run(
             _engine(gate_model, gcfg, mesh=make_host_mesh(data=4, model=1)),
-            gate_params, gcfg.rounds)
+            gcfg.rounds)
         bitwise = _history_bitwise(hist_meshless, hist_m1)
     emit("model_fl/model_axis1_bitwise", 0.0, bitwise)
 

@@ -9,6 +9,8 @@ Prints ``name,us_per_call,derived`` CSV rows.
                                          # reduced sizes AND exit 1 when
                                          # any written acceptance fails
 
+A suite that raises makes the harness exit 1 in every mode.
+
 Suites are declared in the ``SUITES`` registry below: ``(name, module,
 knob)`` where ``knob`` names the reduced-size keyword the module's
 ``run()`` accepts under ``--quick`` (``"rounds"`` for the paper-figure
@@ -21,6 +23,8 @@ import argparse
 import importlib
 import sys
 import time
+
+from repro.compile_cache import enable_compile_cache
 
 QUICK_ROUNDS = 25
 
@@ -55,12 +59,13 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--smoke", action="store_true",
                     help="reduced sizes like --quick, but exit 1 when a "
-                         "suite errors or writes a failed acceptance")
+                         "suite writes a failed acceptance")
     args = ap.parse_args()
+    enable_compile_cache()
     only = args.only or args.suite
     reduced = args.quick or args.smoke
 
-    failed = False
+    failed = errored = False
     print("name,us_per_call,derived")
     for name, module, knob in SUITES:
         if only and only not in name:
@@ -80,10 +85,10 @@ def main() -> None:
                 failed |= not all(report["acceptance"].values())
         except Exception as e:  # keep the harness going; surface the failure
             print(f"{name}/ERROR,0,{type(e).__name__}:{e}", flush=True)
-            failed = True
+            errored = True
         print(f"# {name} done in {time.time()-t0:.1f}s", file=sys.stderr,
               flush=True)
-    if args.smoke and failed:
+    if errored or (args.smoke and failed):
         sys.exit(1)
 
 

@@ -7,6 +7,7 @@ Benign clients hover near +1; Byzantine clients go negative or explode.
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.attacks import AttackConfig
 from repro.data import FederatedData, make_mnist_like, partition_sorted_shards
 from repro.fl import FLConfig, Federation, run_federated_training
@@ -15,6 +16,7 @@ from repro.optim import inv_sqrt_lr
 
 
 def main():
+    enable_compile_cache()
     x, y = make_mnist_like(jax.random.PRNGKey(0), 4600)
     tx, ty = make_mnist_like(jax.random.PRNGKey(9), 500)
     data = FederatedData.from_partitions(partition_sorted_shards(x, y, 23), 10)

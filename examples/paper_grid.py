@@ -13,6 +13,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.attacks import AttackConfig
 from repro.data import FederatedData, make_mnist_like, partition_sorted_shards
 from repro.fl import (FLConfig, Federation, SweepSpec, group_cells,
@@ -29,6 +30,7 @@ SEEDS = (0, 1, 2)
 
 
 def main():
+    enable_compile_cache()
     x, y = make_mnist_like(jax.random.PRNGKey(0), 4600)
     tx, ty = make_mnist_like(jax.random.PRNGKey(9), 1000)
     data = FederatedData.from_partitions(partition_sorted_shards(x, y, 23), 10)

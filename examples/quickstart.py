@@ -9,6 +9,7 @@ under a Gaussian attack.
 """
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.attacks import AttackConfig
 from repro.data import FederatedData, make_mnist_like, partition_sorted_shards
 from repro.fl import (FLConfig, Federation, available_aggregators,
@@ -18,6 +19,7 @@ from repro.optim import inv_sqrt_lr
 
 
 def main():
+    enable_compile_cache()
     x, y = make_mnist_like(jax.random.PRNGKey(0), 4600)
     tx, ty = make_mnist_like(jax.random.PRNGKey(9), 1000)
     data = FederatedData.from_partitions(partition_sorted_shards(x, y, 23), 10)

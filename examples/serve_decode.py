@@ -10,9 +10,11 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs, models
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="h2o-danube-1.8b",
                     choices=configs.all_arch_ids())

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import save_checkpoint
+from repro.compile_cache import enable_compile_cache
 from repro.core.attacks import AttackConfig
 from repro.fl import FLConfig, RoundEngine, make_zoo_federation, zoo_model
 from repro.launch.mesh import make_host_mesh
@@ -33,6 +34,7 @@ from repro.models import ModelConfig
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rounds", "--steps", dest="rounds", type=int,
                     default=20)

@@ -953,6 +953,35 @@ class RoundEngine:
                                                scen)
         return carry, key, logs
 
+    def _training_args(self, params, key, lrs, scen):
+        """``run_training``'s operands of the one-dispatch program —
+        ``(carry, (S, T) subkeys, (S, T) lrs, scen)`` for its S full
+        eval segments — plus what a partial tail segment reads: the
+        advanced key and the whole (R,) subkey and lr vectors."""
+        if scen is None:
+            scen = self.default_scenario
+        lrs = jnp.asarray(lrs, jnp.float32)
+        R = int(lrs.shape[0])
+        T = self.eval_every
+        S = R // T
+        key, subs = self._segment_keys(key, R)
+        # (R, *key) -> (S, T, *key): agnostic to the PRNG key
+        # representation (raw uint32 pairs today, typed keys tomorrow)
+        args = (self._prepare_carry(params),
+                subs[:S * T].reshape((S, T) + subs.shape[1:]),
+                lrs[:S * T].reshape(S, T), scen)
+        return args, key, subs, lrs
+
+    def lower_training(self, params, key, lrs, scen=None):
+        """Lower the one-dispatch program exactly as ``run_training``
+        with these arguments calls it (its S full eval segments); a
+        partial tail segment is a separate program.  Lowering reads
+        shapes only, so donated params stay usable.  ``.compile()`` on
+        the result gives the executable's HLO text and memory analysis."""
+        args = self._training_args(params, key, lrs, scen)[0]
+        with use_mesh(self.mesh):
+            return self._training.lower(*args)
+
     def run_training(self, params, key, lrs, scen=None):
         """Run ``len(lrs)`` rounds as one device-resident program.
 
@@ -974,24 +1003,15 @@ class RoundEngine:
         points, so callers cannot drift from the segmentation that
         actually ran.
         """
-        if scen is None:
-            scen = self.default_scenario
-        lrs = jnp.asarray(lrs, jnp.float32)
+        args, key, subs, lrs = self._training_args(params, key, lrs, scen)
+        carry, scen = args[0], args[3]
         R = int(lrs.shape[0])
         T = self.eval_every
         S, rem = divmod(R, T)
-        key, subs = self._segment_keys(key, R)
-        carry = self._prepare_carry(params)
         with use_mesh(self.mesh):
             metrics, tel = None, None
             if S:
-                # (R, *key) -> (S, T, *key): agnostic to the PRNG key
-                # representation (raw uint32 pairs today, typed keys
-                # tomorrow)
-                carry, (metrics, tel) = self._training(
-                    carry,
-                    subs[:S * T].reshape((S, T) + subs.shape[1:]),
-                    lrs[:S * T].reshape(S, T), scen)
+                carry, (metrics, tel) = self._training(*args)
                 # (S, T, ...) segment-stacked telemetry -> (R', ...)
                 tel = jax.tree.map(
                     lambda x: x.reshape((S * T,) + x.shape[2:]), tel)

@@ -291,12 +291,6 @@ class SecureServer:
         version = self.enclave.seal_version
         if refresh or self._guide_cache is None \
                 or self._guide_cache[0] != version:
-            if not jax.core.trace_state_clean():
-                raise RuntimeError(
-                    "guide_batches cache rebuild attempted under an active "
-                    "JAX trace — the unsealed arrays would be cached as "
-                    "tracers and leak.  Warm the cache eagerly first "
-                    "(fl/engine.make_round_body does this).")
             ids = self.enclave.client_ids()
             if not ids:
                 raise RuntimeError(
@@ -305,9 +299,16 @@ class SecureServer:
             unsealed = {j: self.enclave.unseal_samples(j) for j in ids}
             zx, zy = jax.tree.map(jnp.zeros_like, unsealed[ids[0]])
             rows = [unsealed.get(j, (zx, zy)) for j in range(max(ids) + 1)]
-            self._guide_cache = (version,
-                                 jnp.stack([r[0] for r in rows]),
-                                 jnp.stack([r[1] for r in rows]))
+            gx = jnp.stack([r[0] for r in rows])
+            gy = jnp.stack([r[1] for r in rows])
+            if isinstance(gx, jax.core.Tracer) \
+                    or isinstance(gy, jax.core.Tracer):
+                raise RuntimeError(
+                    "guide_batches cache rebuild attempted under an active "
+                    "JAX trace — the unsealed arrays would be cached as "
+                    "tracers and leak.  Warm the cache eagerly first "
+                    "(fl/engine.make_round_body does this).")
+            self._guide_cache = (version, gx, gy)
             self.audit.append("guide_cache_rebuild", version=version,
                               clients=len(ids))
         return self._guide_cache[1], self._guide_cache[2]

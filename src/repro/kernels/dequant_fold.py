@@ -13,28 +13,50 @@ clients, and added to the carried (1, chunk) accumulator tile — so the
 aggregation pass reads 1 byte per update element instead of 4, and
 decompression costs zero extra HBM passes over U.
 
-Grid: (D/chunk,) with ``chunk`` a qblock multiple.  Blocks: weights
-(n, 1) pinned; q (n, chunk) int8; scales (n, chunk/qblock) f32; the
-accumulator (1, chunk) tile rides along and its buffer is donated via
-``input_output_aliases`` — the same streaming-update contract as
-``masked_agg.masked_agg_update_kernel``, which remains the fold kernel
-for dense-payload codecs (its in-kernel f32 cast is bf16's whole
-dequantization).
+Grid: (D/chunk, n/CLIENT_TILE) with ``chunk`` a qblock multiple — the
+client-axis fold of ``masked_agg.masked_agg_update_kernel``, whose grid
+and row masking it shares.  Blocks: weights (CLIENT_TILE, 1); q
+(CLIENT_TILE, chunk) int8; scales (CLIENT_TILE, chunk/qblock) f32; the
+accumulator (1, chunk) tile stays in VMEM across the client tiles and
+its buffer is donated via ``input_output_aliases``.  That kernel remains
+the fold for dense-payload codecs (its in-kernel f32 cast is bf16's
+whole dequantization).  Nothing is padded in HBM: columns past D read
+unspecified payload and scales but only reach output columns that are
+sliced away.
 
 Numerics: the kernel computes ``(q·scale)·w`` with the identical
 products and the identical axis-0 reduction as the reference
 ``kernels/ref.dequant_fold_ref``, so on exact-data cases (0/1 weights,
 products representable) the two agree bitwise; in general the guarantee
-is the usual block-fold fp tolerance (DESIGN.md §10).  Scale padding is
-zeros, so padded columns contribute exact ±0.0.
+is the usual block-fold fp tolerance (DESIGN.md §10).
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .masked_agg import DEFAULT_CHUNK
+from .masked_agg import DEFAULT_CHUNK, fold_grid, row_mask
+
+
+def _kernel(w_ref, q_ref, s_ref, acc_ref, out_ref, *, n: int, tn: int,
+            qblock: int):
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        out_ref[...] = acc_ref[...]
+
+    wt = w_ref[...]                             # (tn, 1) weights
+    qf = q_ref[...].astype(jnp.float32)         # (tn, chunk) int8 tile
+    s = s_ref[...]                              # (tn, cb) block scales
+    sc = jnp.broadcast_to(s[:, :, None],
+                          s.shape + (qblock,)).reshape(qf.shape)
+    x = (qf * sc) * wt
+    ok = row_mask(n, tn)
+    if ok is not None:
+        x = jnp.where(ok, x, 0.0)
+    out_ref[...] += jnp.sum(x, axis=0, keepdims=True)
 
 
 def dequant_fold_update_kernel(q, scale, w, acc, *, qblock: int,
@@ -45,45 +67,25 @@ def dequant_fold_update_kernel(q, scale, w, acc, *, qblock: int,
     q: (n, D) int8 payload; scale: (n, nb) f32 per-block scales with
     nb = ceil(D / qblock); w: (n,) raw per-client weights (mask already
     folded in, NO 1/|kept| normalization — that happens once at
-    ``finalize``); acc: (D,) the carried AggState partial sum.  The
-    payload is padded to nb·qblock (the decoder's padding) and then to a
-    chunk multiple with zero scales, so padding contributes exact 0.
+    ``finalize``); acc: (D,) the carried AggState partial sum.
     """
     n, d = q.shape
     nb = scale.shape[1]
-    w = w.astype(jnp.float32).reshape(n, 1)
-    scale = scale.astype(jnp.float32)
-    acc2 = acc.astype(jnp.float32).reshape(1, d)
     # chunk must tile in whole quantization blocks
     chunk = max(qblock, (min(chunk, nb * qblock) // qblock) * qblock)
-    d_p = -(-(nb * qblock) // chunk) * chunk
-    if d_p != d:
-        q = jnp.pad(q, ((0, 0), (0, d_p - d)))
-        acc2 = jnp.pad(acc2, ((0, 0), (0, d_p - d)))
-    nb_p = d_p // qblock
-    if nb_p != nb:
-        scale = jnp.pad(scale, ((0, 0), (0, nb_p - nb)))
+    tn, chunk, grid = fold_grid(n, nb * qblock, chunk)
     cb = chunk // qblock
-
-    def _kernel(w_ref, q_ref, s_ref, acc_ref, out_ref):
-        wt = w_ref[...]                             # (n, 1) weights
-        qf = q_ref[...].astype(jnp.float32)         # (n, chunk) int8 tile
-        s = s_ref[...]                              # (n, cb) block scales
-        sc = jnp.broadcast_to(s[:, :, None],
-                              (s.shape[0], cb, qblock)).reshape(qf.shape)
-        out_ref[...] = acc_ref[...] + jnp.sum((qf * sc) * wt, axis=0,
-                                              keepdims=True)
-
     out = pl.pallas_call(
-        _kernel,
-        grid=(d_p // chunk,),
-        in_specs=[pl.BlockSpec((n, 1), lambda i: (0, 0)),
-                  pl.BlockSpec((n, chunk), lambda i: (0, i)),
-                  pl.BlockSpec((n, cb), lambda i: (0, i)),
-                  pl.BlockSpec((1, chunk), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((1, chunk), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, d_p), jnp.float32),
+        functools.partial(_kernel, n=n, tn=tn, qblock=qblock),
+        grid=grid,
+        in_specs=[pl.BlockSpec((tn, 1), lambda i, k: (k, 0)),
+                  pl.BlockSpec((tn, chunk), lambda i, k: (k, i)),
+                  pl.BlockSpec((tn, cb), lambda i, k: (k, i)),
+                  pl.BlockSpec((1, chunk), lambda i, k: (0, i))],
+        out_specs=pl.BlockSpec((1, chunk), lambda i, k: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
         input_output_aliases={3: 0},
         interpret=interpret,
-    )(w, q, scale, acc2)
-    return out[0, :d]
+    )(w.astype(jnp.float32).reshape(n, 1), q, scale.astype(jnp.float32),
+      acc.astype(jnp.float32).reshape(1, d))
+    return out[0]

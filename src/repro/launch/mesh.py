@@ -13,18 +13,10 @@ import jax
 
 
 def _mesh(shape, axes):
-    """One mesh constructor for every helper here: newer JAX wants
-    explicit Auto axis types; older JAX builds the device array
-    directly.  Same mesh either way."""
-    if hasattr(jax.sharding, "AxisType"):   # newer JAX
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,)
-                             * len(axes))
-    import math
-    import numpy as np
-    need = math.prod(shape)
-    return jax.sharding.Mesh(
-        np.array(jax.devices()[:need]).reshape(shape), axes)
+    """One mesh constructor for every helper here: Auto axis types, so
+    GSPMD partitions everything the program does not pin."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -59,13 +51,7 @@ def make_host_pod_mesh(pods: int = 2, data: int = 1, model: int = 1):
             f"needs {need} devices but only {n} are available; force host "
             f"devices with XLA_FLAGS=--xla_force_host_platform_device_count"
             f"={need} before jax initializes")
-    axes = ("pod", "data", "model")
-    if hasattr(jax.sharding, "AxisType"):   # newer JAX
-        return jax.make_mesh((pods, data, model), axes,
-                             axis_types=(jax.sharding.AxisType.Auto,) * 3)
-    import numpy as np
-    return jax.sharding.Mesh(
-        np.array(jax.devices()[:need]).reshape(pods, data, model), axes)
+    return _mesh((pods, data, model), ("pod", "data", "model"))
 
 
 def client_axes(mesh) -> tuple:

@@ -270,6 +270,7 @@ def main(argv=None):
     import argparse
 
     import numpy as np
+    from ..compile_cache import enable_compile_cache
     from ..core.attacks import AttackConfig
     from ..fl.engine import RoundEngine
     from ..fl.simulator import FLConfig
@@ -287,6 +288,7 @@ def main(argv=None):
                     help="number of sign-flipping clients")
     ap.add_argument("--eval-every", type=int, default=5)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     n_dev = len(jax.devices())
     mesh = make_host_mesh(data=max(1, n_dev // 2), model=2 if n_dev > 1 else 1)

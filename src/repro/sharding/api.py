@@ -55,14 +55,6 @@ def use_mesh(mesh: Optional[Mesh]):
         set_mesh(prev)
 
 
-def _abstract_mesh():
-    """The trace context's abstract mesh, or None on JAX versions
-    without one (older releases build constraints from the concrete
-    mesh directly, which is also what an empty abstract mesh means)."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    return None if fn is None else fn()
-
-
 def shard(x, spec: P):
     """Constrain ``x`` to ``spec`` when a mesh is active; no-op otherwise.
 
@@ -86,8 +78,8 @@ def shard(x, spec: P):
             size *= mesh.shape[n]
         if dim % size != 0:
             return x
-    am = _abstract_mesh()
-    if am is not None and not am.empty and MODEL_AXIS in am.axis_names:
+    am = jax.sharding.get_abstract_mesh()
+    if not am.empty and MODEL_AXIS in am.axis_names:
         return jax.lax.with_sharding_constraint(x, NamedSharding(am, spec))
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
@@ -392,8 +384,8 @@ def shard_flat(x):
             x, P(*([None] * (x.ndim - 1) + [MODEL_AXIS])), mesh)
     if spec is None:
         return x
-    am = _abstract_mesh()
-    if am is not None and not am.empty and MODEL_AXIS in am.axis_names:
+    am = jax.sharding.get_abstract_mesh()
+    if not am.empty and MODEL_AXIS in am.axis_names:
         return jax.lax.with_sharding_constraint(x, NamedSharding(am, spec))
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
@@ -428,8 +420,8 @@ def shard_updates(x, axis: int = 0):
         spec = _tiling_spec(x, spec, mesh)
         if spec is None:
             return x
-    am = _abstract_mesh()
-    if (am is not None and not am.empty
+    am = jax.sharding.get_abstract_mesh()
+    if (not am.empty
             and all(n in am.axis_names for e in spec if e is not None
                     for n in (e if isinstance(e, tuple) else (e,)))):
         return jax.lax.with_sharding_constraint(x, NamedSharding(am, spec))

@@ -219,6 +219,11 @@ def test_quantize_tree_lossless_is_identity_lossy_rounds():
 # the fused dequantize-and-fold kernel vs the reference decoder
 # ----------------------------------------------------------------------
 
+# compiled like the kernel: XLA:CPU may reduce an op-by-op (eager) sum in
+# another order than the same sum inside a compiled program
+_dequant_fold_ref_jit = jax.jit(dequant_fold_ref, static_argnames="qblock")
+
+
 @pytest.mark.parametrize("n,d,qblock", [(5, 40, 16), (7, 300, 128),
                                         (16, 2 * QBLOCK, QBLOCK)])
 def test_dequant_fold_kernel_matches_ref_bitwise(n, d, qblock):
@@ -230,7 +235,7 @@ def test_dequant_fold_kernel_matches_ref_bitwise(n, d, qblock):
                     * rng.uniform(0, 2, n).astype(np.float32))
     acc = jnp.asarray(rng.normal(size=(d,)).astype(np.float32))
     got = ops.dequant_fold_update(q, scale, w, acc, qblock=qblock)
-    want = dequant_fold_ref(q, scale, w, acc, qblock)
+    want = _dequant_fold_ref_jit(q, scale, w, acc, qblock=qblock)
     assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -244,7 +249,7 @@ def test_dequant_fold_kernel_chunked_matches_ref():
     acc = jnp.zeros((d,), jnp.float32)
     got = ops.dequant_fold_update(q, scale, w, acc, qblock=qblock,
                                   chunk=2 * qblock)       # multi-tile grid
-    want = dequant_fold_ref(q, scale, w, acc, qblock)
+    want = _dequant_fold_ref_jit(q, scale, w, acc, qblock=qblock)
     assert np.array_equal(np.asarray(got), np.asarray(want))
 
 

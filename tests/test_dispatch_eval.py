@@ -283,6 +283,27 @@ def test_donate_knob_threads_through(fed_data):
     assert engine(donate=False).donate is False
 
 
+def test_lower_training_traces_the_program_run_training_runs(fed_data):
+    """lower_training hands the one-dispatch program run_training's own
+    operands: after lowering, the run reuses that trace instead of
+    tracing a program of its own."""
+    from repro.fl.engine import trace_counter
+    data, tx, ty = fed_data
+    model = softmax_regression(input_dim=DIM, n_classes=N_CLASSES)
+    cfg = _cfg()
+    fed = Federation.create(model, data, tx, ty, cfg, jax.random.PRNGKey(2))
+    engine = RoundEngine(model, fed, cfg)
+    params = model.init(jax.random.PRNGKey(1))
+    lrs = jnp.full((cfg.rounds,), 0.05, jnp.float32)
+    with trace_counter() as tc:
+        compiled = engine.lower_training(
+            params, jax.random.PRNGKey(0), lrs).compile()
+        assert tc["training"] == 1
+        engine.run_training(params, jax.random.PRNGKey(0), lrs)
+        assert tc["training"] == 1
+    assert "while" in compiled.as_text()
+
+
 def test_donate_forced_on_still_runs(fed_data):
     """donate=True on CPU compiles and runs (XLA ignores the request);
     the numbers cannot change."""

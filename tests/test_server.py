@@ -168,6 +168,28 @@ def test_guide_batches_stay_id_aligned_after_drop():
                                    jnp.float32(0.0), DiverseFLConfig()))
 
 
+def test_guide_batches_rebuild_under_jit_raises():
+    """A stale cache must not be rebuilt inside a trace: the stacked
+    arrays would be tracers cached past the trace.  The eager cache stays
+    usable, and the next eager call rebuilds it."""
+    server = SecureServer()
+    _ingest(server)
+    gx0, _ = server.guide_batches()
+    x, y = server.enclave.unseal_samples(0)
+    server.enclave.seal_samples(0, x, 4 - y)       # stale: version moved
+
+    @jax.jit
+    def traced(z):
+        gx, _ = server.guide_batches()
+        return z + gx.sum()
+
+    with pytest.raises(RuntimeError, match="under an active JAX trace"):
+        traced(jnp.float32(0.0))
+    assert server._guide_cache[1] is gx0           # no tracer was cached
+    _, gy = server.guide_batches()
+    np.testing.assert_array_equal(np.asarray(gy[0]), 4 - np.asarray(y))
+
+
 def test_guide_batches_empty_store_raises():
     server = SecureServer()
     with pytest.raises(RuntimeError, match="no sealed samples"):

@@ -5,18 +5,6 @@ import subprocess
 import sys
 import textwrap
 
-import jax
-import pytest
-
-# The pod-scale launch layer uses the modern sharding API (explicit
-# jax.sharding.AxisType meshes + jax.shard_map).  On older JAX (such as
-# the pinned CPU CI build) these attributes don't exist, so the whole
-# module is environment-gated; the subprocesses inherit this env.
-pytestmark = pytest.mark.skipif(
-    not (hasattr(jax.sharding, "AxisType") and hasattr(jax, "shard_map")),
-    reason="launch/ sharded round step needs jax.sharding.AxisType + "
-           "jax.shard_map (newer JAX than this environment provides)")
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
