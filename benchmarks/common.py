@@ -21,7 +21,7 @@ import jax
 
 from repro.core.attacks import AttackConfig
 from repro.data import FederatedData, make_mnist_like, partition_sorted_shards
-from repro.fl import FLConfig, Federation, run_federated_training, telemetry
+from repro.fl import FLConfig, Federation, run_federated_training
 from repro.fl.small_models import softmax_regression
 from repro.optim import inv_sqrt_lr
 
@@ -64,19 +64,13 @@ def write_report(name: str, *, smoke: bool, acceptance: dict,
     bools are not JSON), written with the repo-standard 2-space indent +
     trailing newline, and the path announced on stderr.  Every report
     stamps the schema version, git SHA, and jax/backend versions
-    (:func:`provenance`); when the flight recorder is live (smoke_main
-    runs each bench under ``telemetry.recording()``) the run's trace is
-    attached as compact span/event counts.  Returns the report dict so
-    ``run()`` can hand it to :func:`smoke_main` for the exit-code
-    gate."""
+    (:func:`provenance`).  Returns the report dict so ``run()`` can
+    hand it to :func:`smoke_main` for the exit-code gate."""
     report = {"schema_version": REPORT_SCHEMA_VERSION,
               "mode": "smoke" if smoke else "full",
               "provenance": provenance(),
               **sections,
               "acceptance": {k: bool(v) for k, v in acceptance.items()}}
-    rec = telemetry.get_recorder()
-    if rec.enabled:
-        report["trace"] = rec.counts()
     path = REPO_ROOT / f"BENCH_{name}.json"
     path.write_text(json.dumps(report, indent=2) + "\n")
     print(f"# wrote {path}", file=sys.stderr, flush=True)
@@ -85,16 +79,14 @@ def write_report(name: str, *, smoke: bool, acceptance: dict,
 
 def smoke_main(run_fn) -> None:
     """The shared ``main()`` of every acceptance-gated bench (engine,
-    streaming, dispatch): parse ``--smoke``, run under the flight
-    recorder (so write_report can attach the trace), print the
-    acceptance dict, exit non-zero when a smoke acceptance fails — one
-    definition instead of a copy per module."""
+    streaming, dispatch): parse ``--smoke``, run, print the acceptance
+    dict, exit non-zero when a smoke acceptance fails — one definition
+    instead of a copy per module."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="reduced sizes; exit 1 on failed acceptance")
     args = ap.parse_args()
-    with telemetry.recording():
-        report = run_fn(smoke=args.smoke)
+    report = run_fn(smoke=args.smoke)
     ok = all(report["acceptance"].values())
     print(f"acceptance: {report['acceptance']}", flush=True)
     if args.smoke and not ok:

@@ -27,8 +27,9 @@ def flatten_updates(updates):
     """pytree with leading client dim N -> (N, D) fp32 matrix + unravel fn."""
     leaves = jax.tree.leaves(updates)
     n = leaves[0].shape[0]
-    flat = jnp.concatenate(
-        [u.reshape(n, -1).astype(jnp.float32) for u in leaves], axis=1)
+    with jax.named_scope("flatten"):
+        flat = jnp.concatenate(
+            [u.reshape(n, -1).astype(jnp.float32) for u in leaves], axis=1)
 
     treedef = jax.tree.structure(updates)
     shapes = [u.shape[1:] for u in leaves]

@@ -48,6 +48,7 @@ class DiverseFLConfig:
 # Similarity statistics
 # ----------------------------------------------------------------------
 
+@jax.named_scope("step4_filter")
 def similarity_stats(z: jnp.ndarray, g: jnp.ndarray):
     """Flat-vector stats: (z·g, ‖z‖², ‖g‖²) in fp32."""
     z = z.astype(jnp.float32)
@@ -70,12 +71,14 @@ def _tree_vdot(a_tree, b_tree):
     return jnp.sum(jnp.stack(jax.tree.leaves(parts)))
 
 
+@jax.named_scope("step4_filter")
 def similarity_stats_tree(z_tree, g_tree):
     """Pytree stats: (z·g, ‖z‖², ‖g‖²), exact fp32, shard-local partials."""
     return (_tree_vdot(z_tree, g_tree), _tree_vdot(z_tree, z_tree),
             _tree_vdot(g_tree, g_tree))
 
 
+@jax.named_scope("step4_filter")
 def similarity_stats_matrix(U, G):
     """Stacked-matrix stats: U, G (N, D) -> per-client (dot, ‖z‖², ‖g‖²)."""
     U = U.astype(jnp.float32)
@@ -83,6 +86,7 @@ def similarity_stats_matrix(U, G):
     return jnp.sum(U * G, axis=1), jnp.sum(U * U, axis=1), jnp.sum(G * G, axis=1)
 
 
+@jax.named_scope("step4_filter")
 def diversefl_mask(dot, z_sq, g_sq, cfg: DiverseFLConfig):
     """Boolean keep-mask from per-client stats (any shape, elementwise).
 
@@ -101,6 +105,7 @@ def c2_ratio(z_sq, g_sq):
     return jnp.sqrt(z_sq / jnp.maximum(g_sq, 1e-30))
 
 
+@jax.named_scope("step4_filter")
 def criterion_logs(dot, z_sq, g_sq):
     """Per-client criterion diagnostics shared by every round-step layer:
     C1 = sign(Δ̃·z), C2 = ‖z‖/‖Δ̃‖, and their product (Fig. 2's y-axis)."""

@@ -156,8 +156,12 @@ def chunked_vmap(fn, args: tuple, chunk: Optional[int] = None):
     if not leaves:
         raise ValueError("chunked_vmap needs at least one array argument")
     C = leaves[0].shape[0]
-    if chunk is None or chunk >= C:
-        return jax.vmap(fn)(*args)
-    blocks, k, C = pad_to_blocks(args, chunk)
-    out = jax.lax.map(lambda a: jax.vmap(fn)(*a), blocks)
-    return unblock(out, k, chunk, C)
+    # the map's padding, output stacking and unblocking are the flatten
+    # stage; the stage scope of ``fn`` nests inside and wins (DESIGN.md
+    # §11)
+    with jax.named_scope("flatten"):
+        if chunk is None or chunk >= C:
+            return jax.vmap(fn)(*args)
+        blocks, k, C = pad_to_blocks(args, chunk)
+        out = jax.lax.map(lambda a: jax.vmap(fn)(*a), blocks)
+        return unblock(out, k, chunk, C)

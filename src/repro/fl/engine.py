@@ -197,18 +197,20 @@ def _apply_update_attacks(U, byz_rows, keys_rows, ka, acfg, scen):
     if acfg.kind not in UPDATE_ATTACKS and acfg.kind != "backdoor":
         return U
     sigma, scale = scen["sigma"], scen["scale"]
-    if acfg.kind == "gaussian":          # the only RNG-consuming attack
-        U_att = jax.vmap(
-            lambda u, k: attack_update(u, acfg.kind, k, acfg,
-                                       sigma=sigma, scale=scale))(U, keys_rows)
-    else:
-        U_att = jax.vmap(
-            lambda u: attack_update(u, acfg.kind, ka, acfg,
-                                    sigma=sigma, scale=scale))(U)
-    # (c, 1) on the classic flat layout — a[:, None] verbatim — and
-    # (c, 1, 1) on the blocked (c, ms, L) layout (DESIGN.md §12)
-    bsel = byz_rows.reshape(byz_rows.shape + (1,) * (U.ndim - 1))
-    return jnp.where(bsel, U_att, U)
+    with jax.named_scope("attack"):
+        if acfg.kind == "gaussian":      # the only RNG-consuming attack
+            U_att = jax.vmap(
+                lambda u, k: attack_update(u, acfg.kind, k, acfg,
+                                           sigma=sigma, scale=scale))(
+                U, keys_rows)
+        else:
+            U_att = jax.vmap(
+                lambda u: attack_update(u, acfg.kind, ka, acfg,
+                                        sigma=sigma, scale=scale))(U)
+        # (c, 1) on the classic flat layout — a[:, None] verbatim — and
+        # (c, 1, 1) on the blocked (c, ms, L) layout (DESIGN.md §12)
+        bsel = byz_rows.reshape(byz_rows.shape + (1,) * (U.ndim - 1))
+        return jnp.where(bsel, U_att, U)
 
 def make_round_body(model, fed, cfg, *, client_chunk: Optional[int] = None):
     """Build ``body(params, sub, lr, batch) -> (new_params, logs)``.
@@ -306,8 +308,9 @@ def make_round_body(model, fed, cfg, *, client_chunk: Optional[int] = None):
             g = grad_fn(theta, b)
             return jax.tree.map(
                 lambda t, gg: (t - lr * gg).astype(t.dtype), theta, g), None
-        theta, _ = jax.lax.scan(step, params, (xs, ys))
-        return jax.tree.map(lambda a, b: a - b, params, theta)
+        with jax.named_scope("client_sgd"):
+            theta, _ = jax.lax.scan(step, params, (xs, ys))
+            return jax.tree.map(lambda a, b: a - b, params, theta)
 
     def body(carry, sub, lr, batch=None, scen=None):
         astate = None
@@ -362,17 +365,20 @@ def make_round_body(model, fed, cfg, *, client_chunk: Optional[int] = None):
 
         # ---- data-level attacks ----
         if acfg.kind == "label_flip":
-            yb = jnp.where(byz[:, None, None], flip_labels(yb, n_classes), yb)
+            with jax.named_scope("attack"):
+                yb = jnp.where(byz[:, None, None],
+                               flip_labels(yb, n_classes), yb)
         elif acfg.kind == "backdoor":
             def poison(xc, yc):
                 xf = xc.reshape((E * m,) + xc.shape[2:])
                 yf = yc.reshape(E * m)
                 xp, yp = poison_backdoor(xf, yf, acfg)
                 return xp.reshape(xc.shape), yp.reshape(yc.shape)
-            xp, yp = jax.vmap(poison)(xb, yb)
-            bsel = byz.reshape((-1,) + (1,) * (xb.ndim - 1))
-            xb = jnp.where(bsel, xp, xb)
-            yb = jnp.where(byz[:, None, None], yp, yb)
+            with jax.named_scope("attack"):
+                xp, yp = jax.vmap(poison)(xb, yb)
+                bsel = byz.reshape((-1,) + (1,) * (xb.ndim - 1))
+                xb = jnp.where(bsel, xp, xb)
+                yb = jnp.where(byz[:, None, None], yp, yb)
 
         logs = {"byz": byz, "sel": sel}
         root = None
@@ -650,8 +656,10 @@ def make_round_body(model, fed, cfg, *, client_chunk: Optional[int] = None):
         # MODEL_AXIS partition-table layout, so the scan carry keeps its
         # tensor-parallel placement round over round (no-op off a
         # model-sharded mesh — the pre-zoo jaxpr is unchanged)
-        new_params = shard_params(jax.tree.map(
-            lambda p, d: (p - d).astype(p.dtype), params, unravel(delta)))
+        with jax.named_scope("step5_fold"):
+            new_params = shard_params(jax.tree.map(
+                lambda p, d: (p - d).astype(p.dtype), params,
+                unravel(delta)))
         if lossy:
             return (new_params, resid), logs
         if async_mode:
@@ -715,6 +723,7 @@ class RoundEngine:
     benchmarks/dispatch_bench measure the donation working-set delta.
     """
 
+    @telemetry.span("fl.engine")
     def __init__(self, model, fed, cfg, *, eval_every: Optional[int] = None,
                  client_chunk: Optional[int] = None,
                  batch_mode: Optional[str] = None, mesh=None,
@@ -1003,12 +1012,14 @@ class RoundEngine:
         points, so callers cannot drift from the segmentation that
         actually ran.
         """
-        args, key, subs, lrs = self._training_args(params, key, lrs, scen)
+        with telemetry.span("fl.prepare"):
+            args, key, subs, lrs = self._training_args(params, key, lrs,
+                                                       scen)
         carry, scen = args[0], args[3]
         R = int(lrs.shape[0])
         T = self.eval_every
         S, rem = divmod(R, T)
-        with use_mesh(self.mesh):
+        with use_mesh(self.mesh), telemetry.span("fl.launch"):
             metrics, tel = None, None
             if S:
                 carry, (metrics, tel) = self._training(*args)

@@ -162,13 +162,14 @@ def corrupt_updates(U, fault_rows, fcfg: FaultConfig):
     if fcfg.kind != "intermittent":
         return U
     rows = fault_rows.reshape(fault_rows.shape + (1,) * (U.ndim - 1))
-    if fcfg.mode == "nan":
-        bad = jnp.full_like(U, jnp.nan)
-    elif fcfg.mode == "inf":
-        bad = jnp.full_like(U, jnp.inf)
-    else:
-        bad = U * jnp.asarray(fcfg.bitflip_scale, U.dtype)
-    return jnp.where(rows, bad, U)
+    with jax.named_scope("attack"):
+        if fcfg.mode == "nan":
+            bad = jnp.full_like(U, jnp.nan)
+        elif fcfg.mode == "inf":
+            bad = jnp.full_like(U, jnp.inf)
+        else:
+            bad = U * jnp.asarray(fcfg.bitflip_scale, U.dtype)
+        return jnp.where(rows, bad, U)
 
 
 def init_async_state(cfg, flat_shape) -> Optional[dict]:

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 
 from ..core.attacks import AttackConfig
@@ -254,6 +255,7 @@ def make_eval_fn(model, fed, cfg):
     bd = fed.backdoor_eval(acfg) if acfg.kind == "backdoor" else None
     main_mask = None if bd is None else ~bd.src
 
+    @jax.named_scope("eval")
     def eval_fn(params, logs):
         m = {"acc": accuracy(model, params, fed.test_x, fed.test_y)}
         if bd is not None:

@@ -349,6 +349,7 @@ class SecureServer:
                           count=int(count), **extra)
 
     # --- Step 3: guiding updates --------------------------------------
+    @jax.named_scope("guide_sgd")
     def compute_guides(self, params, grad_fn, lr, E: int = 1, select=None,
                        client_chunk: Optional[int] = None, codec=None,
                        flat: bool = False):
@@ -381,6 +382,9 @@ class SecureServer:
         gx, gy = self.guide_batches()
         if select is not None:
             gx, gy = gx[select], gy[select]
+        # chunked_vmap's flatten scope wraps the map, so each guide's SGD
+        # opens guide_sgd again inside it (DESIGN.md §11)
+        guide = jax.named_scope("guide_sgd")(guiding_update)
         if flat:
             from .compression import quantize_tree
             from ..sharding import (model_shard_count, ravel_sharded,
@@ -388,7 +392,7 @@ class SecureServer:
             sharded = model_shard_count() > 1
 
             def one_flat(x, y):
-                g = guiding_update(params, (x, y), grad_fn, lr, E)
+                g = guide(params, (x, y), grad_fn, lr, E)
                 if codec is not None and not codec.lossless:
                     # per-tensor quantization BEFORE the ravel: the wire
                     # blocks (int8 qblock) align with tensor boundaries
@@ -402,19 +406,21 @@ class SecureServer:
                     # same column offsets as the update blocks, so the
                     # Eq. 6 dots align (sharding.ravel_sharded, §12)
                     return ravel_sharded(g)
-                return jnp.concatenate(
-                    [jnp.ravel(l).astype(jnp.float32)
-                     for l in jax.tree.leaves(g)])
+                with jax.named_scope("flatten"):
+                    return jnp.concatenate(
+                        [jnp.ravel(l).astype(jnp.float32)
+                         for l in jax.tree.leaves(g)])
             return shard_updates(chunked_vmap(one_flat, (gx, gy),
                                               client_chunk))
         guides = chunked_vmap(
-            lambda x, y: guiding_update(params, (x, y), grad_fn, lr, E),
+            lambda x, y: guide(params, (x, y), grad_fn, lr, E),
             (gx, gy), client_chunk)
         if codec is not None and not codec.lossless:
             from .compression import quantize_tree   # deferred: no cycle, but
             guides = quantize_tree(codec, guides)    # keep server import-light
         return guides
 
+    @jax.named_scope("guide_sgd")
     def compute_root_update(self, params, grad_fn, lr, E, root_x, root_y):
         """FLTrust's server-side root direction: the same Step-3 SGD on
         the server's root dataset (one pseudo-client, never chunked)."""
@@ -422,6 +428,7 @@ class SecureServer:
 
     # --- Steps 4-5: criterion + aggregation ---------------------------
     @staticmethod
+    @jax.named_scope("step5_fold")
     def aggregate(name: str, U, ctx: AggregationContext):
         return aggregate(name, U, ctx)
 

@@ -362,6 +362,7 @@ class Federation:
         return bd
 
     @classmethod
+    @telemetry.span("fl.federation")
     def create(cls, model: SmallModel, data: FederatedData, test_x, test_y,
                cfg: FLConfig, key):
         k1, k2 = jax.random.split(key)
@@ -566,9 +567,8 @@ def run_federated_training(model: SmallModel, fed: Federation, cfg: FLConfig,
 
     if use_engine and not host_eval:
         with run_span:
-            with telemetry.span("dispatch"):
-                params, key, metrics, eval_rounds = engine.run_training(
-                    params, key, lrs_all, scen)
+            params, key, metrics, eval_rounds = engine.run_training(
+                params, key, lrs_all, scen)
             if metrics is not None:                    # rounds >= 1
                 host = host_sync(metrics)              # THE host sync
                 # the reserved telemetry block rides the same sync and is

@@ -278,6 +278,11 @@ def weighted_mean_rule(weight_fn: Callable, *, floor: float = 1.0,
         return (shard_flat(jnp.zeros(shape, jnp.float32)),
                 jnp.zeros((), jnp.float32))
 
+    # the criterion statistics are Step 4; everything else the state
+    # sees is the Step 5 fold (DESIGN.md §11)
+    weight_fn = jax.named_scope("step4_filter")(weight_fn)
+
+    @jax.named_scope("step5_fold")
     def update(state, u, ctx):
         s, n = state
         ud, fin = _screen(decode(u))
@@ -292,6 +297,7 @@ def weighted_mean_rule(weight_fn: Callable, *, floor: float = 1.0,
     def merge(x, y):
         return jax.tree.map(jnp.add, x, y)
 
+    @jax.named_scope("step5_fold")
     def finalize(state):
         s, n = state
         # the round delta inherits the numerator's model sharding — the
@@ -315,6 +321,7 @@ def weighted_mean_rule(weight_fn: Callable, *, floor: float = 1.0,
         _, a, b, logs = _block(U, ctx_blk)
         return a, b, logs
 
+    @jax.named_scope("step5_fold")
     def update_block(state, U, ctx_blk):
         s, n = state
         ud, a, b, logs = _block(U, ctx_blk)

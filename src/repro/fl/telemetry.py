@@ -8,16 +8,25 @@ observability model — the paper's core claim (the per-client C1/C2
 criterion tags exactly the faulty clients) was only visible by digging
 through raw history arrays, and production TEE-FL deployments (SecFL,
 Separation-of-Powers in PAPERS.md) treat an inspectable trail as a
-first-class requirement.  This module is that trail, in three parts:
+first-class requirement.  This module is that trail, in four parts:
 
   * **Spans + events** — a process-wide :class:`Recorder`.
-    ``span("compile")``/``event(...)`` emit structured records
-    (monotonic wall time, kind, static metadata such as N/D/chunk/pods/
-    codec).  Recording is OFF by default and every instrumentation site
-    is a cheap ``enabled()`` check, so the disabled recorder costs one
+    ``span(name)``/``event(...)`` emit structured records (monotonic
+    wall time, kind, static metadata such as N/D/chunk/pods/codec).
+    Recording is OFF by default and every instrumentation site is a
+    cheap ``enabled()`` check, so the disabled recorder costs one
     attribute read — the instrumented seams (engine trace counters,
     ``simulator.host_sync``, sweep group compiles, streaming fallbacks)
-    stay on the exact pre-telemetry code paths.
+    stay on the exact pre-telemetry code paths.  Every ``span`` also
+    enters a ``jax.profiler.TraceAnnotation`` of the same name, recorder
+    on or off, so under a profiler session the program's host spans land
+    on the trace's host plane, on the device ops' clock.  While
+    :func:`recording` is on, each backend compile adds a ``compile``
+    event (its seconds, and whether the persistent cache served it).
+  * **Stage scopes** — :data:`SCOPES` names the stages of a round; each
+    is a ``jax.named_scope`` in the function that does the work, so every
+    compiled op carries its stage in its ``op_name`` metadata and a
+    device trace can be split by stage (DESIGN.md §11).
   * **On-device round telemetry** — :func:`make_round_telemetry_fn`
     builds the per-round telemetry block the engine accumulates
     *inside* the scan (C1/C2 pass counts, tagged-client popcount,
@@ -47,9 +56,17 @@ import json
 import time
 from typing import Any, Dict, List, Optional
 
+import jax
 import jax.numpy as jnp
 
 SCHEMA_VERSION = 1
+
+# The stages of a round, each a ``jax.named_scope`` in the function that
+# does the work (DESIGN.md §11).  An op belongs to the innermost of these
+# that its ``op_name`` path holds; a device trace is split by them, so
+# the names are a contract with its readers and do not change.
+SCOPES = ("client_sgd", "attack", "flatten", "guide_sgd", "step4_filter",
+          "step5_fold", "eval")
 
 # The hash chain's genesis digest: the first entry commits to this.
 GENESIS = "0" * 64
@@ -122,16 +139,6 @@ class Recorder:
         """The records so far (a copy — safe to mutate/serialize)."""
         return [dict(r) for r in self.records]
 
-    def counts(self) -> Dict[str, int]:
-        """``{"span:<name>"|"event:<kind>": count}`` — the compact
-        summary ``benchmarks/common.write_report`` attaches."""
-        out: Dict[str, int] = {}
-        for r in self.records:
-            k = (f"span:{r['name']}" if r["type"] == "span"
-                 else f"event:{r['kind']}")
-            out[k] = out.get(k, 0) + 1
-        return out
-
 
 _RECORDER = Recorder()
 
@@ -149,9 +156,13 @@ def event(kind: str, **meta) -> None:
     _RECORDER.event(kind, **meta)
 
 
+@contextlib.contextmanager
 def span(name: str, **meta):
-    """Open one span on the process recorder (no-op when disabled)."""
-    return _RECORDER.span(name, **meta)
+    """Open one span on the process recorder (no-op when disabled) and a
+    profiler annotation of the same name (a no-op check when no profiler
+    session is active)."""
+    with jax.profiler.TraceAnnotation(name), _RECORDER.span(name, **meta):
+        yield
 
 
 @contextlib.contextmanager
@@ -162,14 +173,40 @@ def recording(path: Optional[str] = None, audit: Optional["AuditLog"] = None,
     ``path`` exports the flight record as JSONL on exit (including the
     ``audit`` log's hash chain when one is passed); the records also
     stay on the recorder for in-process inspection until the next
-    :func:`recording`.  ``meta`` lands in the export header."""
+    :func:`recording`.  ``meta`` lands in the export header.
+
+    While it is on, every backend compile emits a ``compile`` event:
+    ``program`` (the compiled function's name), ``dur`` (seconds, a
+    persistent-cache read included) and ``cache_hit``."""
     rec = _RECORDER.start()
+    hits = []
+
+    def on_event(event, **_):
+        if event == _CACHE_HIT:
+            hits.append(True)
+
+    def on_duration(event, duration, fun_name="", **_):
+        if event == _BACKEND_COMPILE:
+            rec.event("compile", program=fun_name, dur=round(duration, 6),
+                      cache_hit=bool(hits))
+            hits.clear()
+
+    jax.monitoring.register_event_listener(on_event)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
     try:
         yield rec
     finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+        jax.monitoring.unregister_event_listener(on_event)
         rec.stop()
         if path is not None:
             export_jsonl(path, recorder=rec, audit=audit, meta=meta)
+
+
+# the events JAX records around each backend compile: the compile's
+# duration (a persistent-cache read included), and a cache hit inside it
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
 
 
 # ----------------------------------------------------------------------

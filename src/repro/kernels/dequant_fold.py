@@ -85,6 +85,7 @@ def dequant_fold_update_kernel(q, scale, w, acc, *, qblock: int,
         out_specs=pl.BlockSpec((1, chunk), lambda i, k: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
         input_output_aliases={3: 0},
+        name="dequant_fold",
         interpret=interpret,
     )(w.astype(jnp.float32).reshape(n, 1), q, scale.astype(jnp.float32),
       acc.astype(jnp.float32).reshape(1, d))

@@ -106,6 +106,7 @@ def flash_attention_kernel(q, k, v, *, window=None, softcap=None,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, dh), jnp.float32),
         ],
+        name="flash_attention",
         interpret=interpret,
     )(q, k, v)
     return out[:, :, :Sq, :]

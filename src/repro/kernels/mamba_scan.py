@@ -61,6 +61,7 @@ def mamba_scan_kernel(da, dbx, c, *, bs: int = 64, bd: int = 256,
         out_specs=pl.BlockSpec((1, bs, 1, bd), lambda b, j, s: (b, s, 0, j)),
         out_shape=jax.ShapeDtypeStruct((B, S, 1, di), jnp.float32),
         scratch_shapes=[pltpu.VMEM((n, bd), jnp.float32)],
+        name="mamba_scan",
         interpret=interpret,
     )(da.swapaxes(2, 3), dbx.swapaxes(2, 3), c[..., None])
     return y[:, :, 0, :]

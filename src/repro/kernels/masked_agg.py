@@ -93,6 +93,7 @@ def masked_agg_update_kernel(u, w, acc, *, chunk: int = DEFAULT_CHUNK,
         out_specs=pl.BlockSpec((1, chunk), lambda i, k: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
         input_output_aliases={2: 0},
+        name="masked_agg",
         interpret=interpret,
     )(w.astype(jnp.float32).reshape(n, 1), u,
       acc.astype(jnp.float32).reshape(1, d))

@@ -30,13 +30,17 @@ def _interpret() -> bool:
 @functools.partial(jax.jit, static_argnames=("chunk",))
 def similarity_stats(z, g, chunk: int = _sim.DEFAULT_CHUNK):
     """(N, D) x (N, D) -> (N, 3) fp32 [dot, ||z||^2, ||g||^2]."""
-    return _sim.similarity_kernel(z, g, chunk=chunk, interpret=_interpret())
+    with jax.named_scope("step4_filter"):
+        return _sim.similarity_kernel(z, g, chunk=chunk,
+                                      interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))
 def masked_aggregate(u, mask, chunk: int = _ma.DEFAULT_CHUNK):
     """(N, D), (N,) -> (D,) masked mean (Eq. 6) in one HBM pass over u."""
-    return _ma.masked_agg_kernel(u, mask, chunk=chunk, interpret=_interpret())
+    with jax.named_scope("step5_fold"):
+        return _ma.masked_agg_kernel(u, mask, chunk=chunk,
+                                     interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))
@@ -45,8 +49,9 @@ def masked_agg_update(u, w, acc, chunk: int = _ma.DEFAULT_CHUNK):
     partial -> (D,) ``acc + sum_i w_i * u_i`` in one HBM pass over u.
     The Pallas leg of the streaming AggState ``update_block`` — the
     1/|kept| normalization happens once at ``finalize``, not here."""
-    return _ma.masked_agg_update_kernel(u, w, acc, chunk=chunk,
-                                        interpret=_interpret())
+    with jax.named_scope("step5_fold"):
+        return _ma.masked_agg_update_kernel(u, w, acc, chunk=chunk,
+                                            interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("qblock", "chunk"))
@@ -59,8 +64,10 @@ def dequant_fold_update(q, scale, w, acc, qblock: int,
     of the streaming AggState ``update_block`` (fl/streaming.py); dense-
     payload codecs keep using :func:`masked_agg_update`, whose in-kernel
     f32 cast is their whole dequantization."""
-    return _dq.dequant_fold_update_kernel(q, scale, w, acc, qblock=qblock,
-                                          chunk=chunk, interpret=_interpret())
+    with jax.named_scope("step5_fold"):
+        return _dq.dequant_fold_update_kernel(q, scale, w, acc,
+                                              qblock=qblock, chunk=chunk,
+                                              interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "chunk"))
@@ -71,10 +78,14 @@ def diversefl_step45(u, g, cfg, chunk: int = _sim.DEFAULT_CHUNK):
     Two HBM passes over u (similarity stats, masked mean) and one over g
     — the criterion itself runs on (N,) scalars in registers.  ``cfg`` is
     a (hashable) DiverseFLConfig."""
-    stats = _sim.similarity_kernel(u, g, chunk=chunk, interpret=_interpret())
-    dot, zz, gg = stats[:, 0], stats[:, 1], stats[:, 2]
-    mask = diversefl_mask(dot, zz, gg, cfg)
-    delta = _ma.masked_agg_kernel(u, mask, chunk=chunk, interpret=_interpret())
+    with jax.named_scope("step4_filter"):
+        stats = _sim.similarity_kernel(u, g, chunk=chunk,
+                                       interpret=_interpret())
+        dot, zz, gg = stats[:, 0], stats[:, 1], stats[:, 2]
+        mask = diversefl_mask(dot, zz, gg, cfg)
+    with jax.named_scope("step5_fold"):
+        delta = _ma.masked_agg_kernel(u, mask, chunk=chunk,
+                                      interpret=_interpret())
     return delta, mask, (dot, zz, gg)
 
 

@@ -66,6 +66,7 @@ def robust_agg_kernel(u, f: int = 0, *, chunk: int = DEFAULT_CHUNK,
                    pl.BlockSpec((1, chunk), lambda i: (0, i))],
         out_shape=[jax.ShapeDtypeStruct((1, d), jnp.float32),
                    jax.ShapeDtypeStruct((1, d), jnp.float32)],
+        name="robust_agg",
         interpret=interpret,
     )(u)
     return med[0], trim[0]

@@ -73,6 +73,7 @@ def similarity_kernel(z, g, *, chunk: int = DEFAULT_CHUNK,
                   pl.BlockSpec((tn, chunk), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((tn, STATS_LANES), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, STATS_LANES), jnp.float32),
+        name="similarity",
         interpret=interpret,
     )(z, g)
     return out[:, :3]

@@ -484,20 +484,21 @@ def flatten_updates_sharded(updates):
     n = leaves[0].shape[0]
 
     pieces = []
-    for u, (shape, sz, c, k) in zip(leaves, plans):
-        uf = u.astype(jnp.float32)
-        if k is not None:
-            nk = shape[k]
-            uf = uf.reshape((n,) + shape[:k] + (ms, nk // ms)
-                            + shape[k + 1:])
-            uf = jnp.moveaxis(uf, 1 + k, 1)
-            pieces.append(uf.reshape(n, ms, c))
-        else:
-            p = uf.reshape(n, sz)
-            if c * ms != sz:
-                p = jnp.pad(p, ((0, 0), (0, c * ms - sz)))
-            pieces.append(p.reshape(n, ms, c))
-    flat = shard_updates(jnp.concatenate(pieces, axis=2))
+    with jax.named_scope("flatten"):
+        for u, (shape, sz, c, k) in zip(leaves, plans):
+            uf = u.astype(jnp.float32)
+            if k is not None:
+                nk = shape[k]
+                uf = uf.reshape((n,) + shape[:k] + (ms, nk // ms)
+                                + shape[k + 1:])
+                uf = jnp.moveaxis(uf, 1 + k, 1)
+                pieces.append(uf.reshape(n, ms, c))
+            else:
+                p = uf.reshape(n, sz)
+                if c * ms != sz:
+                    p = jnp.pad(p, ((0, 0), (0, c * ms - sz)))
+                pieces.append(p.reshape(n, ms, c))
+        flat = shard_updates(jnp.concatenate(pieces, axis=2))
 
     def unravel(vec):
         # vec: (ms, L) — slice each leaf's column band and invert its
@@ -529,20 +530,21 @@ def ravel_sharded(tree):
     ms = model_shard_count()
     flat_p, _ = jax.tree_util.tree_flatten_with_path(tree)
     pieces = []
-    for path, u in flat_p:
-        shape, sz, c, k = _leaf_plan(path, u.shape, ms)
-        uf = u.astype(jnp.float32)
-        if k is not None:
-            nk = shape[k]
-            uf = uf.reshape(shape[:k] + (ms, nk // ms) + shape[k + 1:])
-            uf = jnp.moveaxis(uf, k, 0)
-            pieces.append(uf.reshape(ms, c))
-        else:
-            p = uf.reshape(sz)
-            if c * ms != sz:
-                p = jnp.pad(p, (0, c * ms - sz))
-            pieces.append(p.reshape(ms, c))
-    return shard_flat(jnp.concatenate(pieces, axis=1))
+    with jax.named_scope("flatten"):
+        for path, u in flat_p:
+            shape, sz, c, k = _leaf_plan(path, u.shape, ms)
+            uf = u.astype(jnp.float32)
+            if k is not None:
+                nk = shape[k]
+                uf = uf.reshape(shape[:k] + (ms, nk // ms) + shape[k + 1:])
+                uf = jnp.moveaxis(uf, k, 0)
+                pieces.append(uf.reshape(ms, c))
+            else:
+                p = uf.reshape(sz)
+                if c * ms != sz:
+                    p = jnp.pad(p, (0, c * ms - sz))
+                pieces.append(p.reshape(ms, c))
+        return shard_flat(jnp.concatenate(pieces, axis=1))
 
 
 # ----------------------------------------------------------------------

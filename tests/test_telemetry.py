@@ -97,8 +97,7 @@ def test_spans_nest_and_events_interleave():
     assert inner["depth"] == 1 and outer["depth"] == 0
     assert outer["t0"] <= inner["t0"] and inner["t1"] <= outer["t1"]
     assert outer["n"] == 2
-    assert rec.counts() == {"event:tick": 2, "span:inner": 1,
-                            "span:outer": 1}
+    assert [r.get("i") for r in rec.records] == [0, 1, None, None]
 
 
 def test_recording_resets_between_uses():
@@ -300,7 +299,7 @@ def test_export_load_roundtrip(tmp_path, fed_data):
     assert len([e for e in run["events"] if e["kind"] == "sync"]) == 1
     assert len([e for e in run["events"] if e["kind"] == "round"]) == 4
     names = [s["name"] for s in run["spans"]]
-    assert "run_training" in names and "dispatch" in names
+    assert {"run_training", "fl.prepare", "fl.launch"} <= set(names)
 
 
 def test_observe_cli_renders_and_verifies(tmp_path, fed_data, capsys):
