@@ -53,7 +53,7 @@ from .compression import encode_with_feedback, get_codec
 from .faults import (corrupt_updates, draw_faults, init_async_state,
                      make_cohort_chain, validate_cohort_chain)
 from .metrics import make_eval_fn
-from .server import AggregationContext, get_aggregator
+from .server import AggregationContext, get_aggregator, get_leaf_form
 from .streaming import fallback_reason, get_streaming, stream_aggregate
 
 logger = logging.getLogger(__name__)
@@ -212,6 +212,35 @@ def _apply_update_attacks(U, byz_rows, keys_rows, ka, acfg, scen):
         bsel = byz_rows.reshape(byz_rows.shape + (1,) * (U.ndim - 1))
         return jnp.where(bsel, U_att, U)
 
+
+def dense_rows_reason(cfg, *, streaming: bool, lossy: bool,
+                      async_mode: bool) -> Optional[str]:
+    """Why a round's dense Step 4+5 builds (N, D) update and guide rows,
+    or None when it reads the stacked leaves in place (DESIGN.md §3).
+
+    Leaves need the rule's leaf form running a Pallas kernel: the XLA
+    dense path keeps rows, whose flat association the streaming fold
+    reproduces bit for bit.  Every carry or attack that is itself rows
+    keeps them too."""
+    form = get_leaf_form(cfg.aggregator)
+    if form is None:
+        return f"aggregator {cfg.aggregator!r} has no leaf form"
+    if not any(getattr(cfg, f) for f in form.kernel_flags):
+        return ("XLA dense path: rows keep the bitwise streaming contract "
+                f"(no {' or '.join(form.kernel_flags)})")
+    if async_mode:
+        return "async rounds: the staleness slab is rows"
+    if lossy:
+        return "lossy codec: the error-feedback residual is rows"
+    if streaming:
+        return "streaming fold: client blocks are rows"
+    if model_shard_count() > 1:
+        return "model-sharded: the blocked (ms, L) rows"
+    if cfg.attack.kind == "gaussian":
+        return "gaussian attack: its noise is drawn per flat row"
+    return None
+
+
 def make_round_body(model, fed, cfg, *, client_chunk: Optional[int] = None):
     """Build ``body(params, sub, lr, batch) -> (new_params, logs)``.
 
@@ -289,6 +318,13 @@ def make_round_body(model, fed, cfg, *, client_chunk: Optional[int] = None):
                 cfg.aggregator, streaming_fallback)
             telemetry.event("streaming_fallback", aggregator=cfg.aggregator,
                             reason=streaming_fallback)
+    rows_reason = dense_rows_reason(
+        cfg, streaming=stream_entry is not None, lossy=lossy,
+        async_mode=async_mode)
+    leaves = rows_reason is None
+    telemetry.event("dense_layout", aggregator=cfg.aggregator,
+                    layout="leaves" if leaves else "rows",
+                    reason=rows_reason)
     if entry.needs_guides:
         # Unseal + cache the guide batches *eagerly*, outside any trace:
         # building the device-side cache under jit/scan tracing would
@@ -612,6 +648,32 @@ def make_round_body(model, fed, cfg, *, client_chunk: Optional[int] = None):
                 logs["stale_buffered"] = stale_buffered
                 logs["stale_folded"] = stale_folded
                 logs["stale_expired"] = stale_expired
+        elif leaves:
+            # ---- Steps 2-5 on the stacked leaves: no (N, D) rows ----
+            updates = chunked_vmap(
+                lambda x, y: client_update(params, x, y, lr), (xb, yb),
+                client_chunk)
+            if acfg.kind in UPDATE_ATTACKS or acfg.kind == "backdoor":
+                # every attack but gaussian is elementwise on a client's
+                # update: the row select, leaf by leaf, in float32 as on
+                # the rows
+                updates = jax.tree.map(
+                    lambda u: _apply_update_attacks(
+                        u.astype(jnp.float32), byz, None, ka, acfg, scen),
+                    updates)
+            updates = jax.tree.map(shard_clients, updates)
+            G = None
+            if entry.needs_guides:
+                G = jax.tree.map(shard_clients, fed.server.compute_guides(
+                    params, grad_fn, lr, E, select=sel,
+                    client_chunk=client_chunk))
+            ctx = AggregationContext(
+                key=kr, f=cfg.f, dfl=cfg.dfl, byz_mask=byz, guides=G,
+                use_kernel_stats=cfg.use_kernel_stats,
+                use_kernel_agg=cfg.use_kernel_agg)
+            delta, agg_logs = fed.server.aggregate_leaves(cfg.aggregator,
+                                                          updates, ctx)
+            logs.update(agg_logs)
         else:
             # ---- Step 2: client local training (chunked federation) ----
             updates = chunked_vmap(
@@ -657,9 +719,10 @@ def make_round_body(model, fed, cfg, *, client_chunk: Optional[int] = None):
         # tensor-parallel placement round over round (no-op off a
         # model-sharded mesh — the pre-zoo jaxpr is unchanged)
         with jax.named_scope("step5_fold"):
+            if not leaves:
+                delta = unravel(delta)
             new_params = shard_params(jax.tree.map(
-                lambda p, d: (p - d).astype(p.dtype), params,
-                unravel(delta)))
+                lambda p, d: (p - d).astype(p.dtype), params, delta))
         if lossy:
             return (new_params, resid), logs
         if async_mode:
@@ -668,6 +731,8 @@ def make_round_body(model, fed, cfg, *, client_chunk: Optional[int] = None):
 
     body.streaming = stream_entry is not None
     body.streaming_fallback = streaming_fallback
+    body.dense_layout = "leaves" if leaves else "rows"
+    body.dense_layout_reason = rows_reason
     body.lossy = lossy
     body.codec = codec
     body.async_mode = async_mode
@@ -751,6 +816,10 @@ class RoundEngine:
         # (streaming requested but rule not associative), why not
         self.streaming = self._body.streaming
         self.streaming_fallback = self._body.streaming_fallback
+        # does the dense Step 4+5 read the stacked leaves, or build rows
+        # (and why)
+        self.dense_layout = self._body.dense_layout
+        self.dense_layout_reason = self._body.dense_layout_reason
         # lossy compression threads an (N, d) error-feedback residual
         # through every carry: the engine's params slot becomes
         # (params, resid) and callers go through init_carry/carry_params

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 
 from ..core import aggregators as agg
 from ..core.diversefl import (DiverseFLConfig, criterion_logs, diversefl_mask,
-                              guiding_update, masked_mean_flat,
+                              guiding_update, masked_mean, masked_mean_flat,
                               similarity_stats_matrix)
 from ..core.tee import Enclave
 from .chunking import chunked_vmap
@@ -71,7 +71,9 @@ class AggregationContext:
     f: int = 0                               # Byzantine budget
     dfl: DiverseFLConfig = DiverseFLConfig()
     byz_mask: Optional[jnp.ndarray] = None   # ground truth (oracle only)
-    guides: Optional[jnp.ndarray] = None     # G (N, D) — enclave Step 3
+    guides: Optional[jnp.ndarray] = None     # G (N, D) — enclave Step 3;
+    #                                          the guide pytree for a leaf
+    #                                          form
     root_update: Optional[jnp.ndarray] = None  # FLTrust root direction
     resample_s: int = 2
     use_kernel_stats: bool = False           # Pallas similarity kernel
@@ -226,6 +228,67 @@ def _fltrust(U, ctx):
             Uf, ts * (rn / un), jnp.zeros((U.shape[1],), jnp.float32))
         return s / jnp.maximum(ts.sum(), 1e-12), {}
     return agg.fltrust(U, ctx.root_update), {}
+
+
+# ----------------------------------------------------------------------
+# Leaf forms (DESIGN.md §3): the masked-mean family's Step 4+5 on the
+# stacked per-client update pytrees, ``fn(updates, ctx) -> (delta pytree,
+# logs)`` with ``ctx.guides`` the guide pytree, through the leaf kernels
+# (kernels/ops.py) — no (N, D) rows are built.  ``kernel_flags`` are the
+# context flags under which the rule's dense form runs a Pallas kernel;
+# only then may a dense round read leaves (fl/engine.dense_rows_reason).
+# ----------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class LeafForm:
+    fn: Callable
+    kernel_flags: Tuple[str, ...]
+
+
+_LEAF_FORMS: Dict[str, LeafForm] = {}
+
+
+def register_leaf_form(name: str, *, kernel_flags: Tuple[str, ...]):
+    """Decorator: register the leaf form of the registered rule ``name``."""
+    def deco(fn):
+        get_aggregator(name)
+        _LEAF_FORMS[name] = LeafForm(fn, kernel_flags)
+        return fn
+    return deco
+
+
+def get_leaf_form(name: str) -> Optional[LeafForm]:
+    return _LEAF_FORMS.get(name)
+
+
+@register_leaf_form("diversefl",
+                    kernel_flags=("use_kernel_stats", "use_kernel_agg"))
+def _diversefl_leaves(updates, ctx):
+    from ..kernels import ops as kops
+    if ctx.use_kernel_agg:
+        delta, mask, (dot, zz, gg) = kops.diversefl_step45_leaves(
+            updates, ctx.guides, ctx.dfl)
+    else:
+        dot, zz, gg = kops.similarity_stats_leaves(updates, ctx.guides)
+        mask = diversefl_mask(dot, zz, gg, ctx.dfl)
+        delta = masked_mean(updates, mask)
+    return delta, {"mask": mask, "z_sq": zz, "g_sq": gg,
+                   **criterion_logs(dot, zz, gg)}
+
+
+@register_leaf_form("oracle", kernel_flags=("use_kernel_agg",))
+def _oracle_leaves(updates, ctx):
+    from ..kernels import ops as kops
+    mask = ~ctx.byz_mask
+    return kops.masked_aggregate_leaves(updates, mask), {"mask": mask}
+
+
+@register_leaf_form("mean", kernel_flags=("use_kernel_agg",))
+def _mean_leaves(updates, ctx):
+    from ..kernels import ops as kops
+    n = jax.tree.leaves(updates)[0].shape[0]
+    return kops.masked_aggregate_leaves(
+        updates, jnp.ones((n,), jnp.float32)), {}
 
 
 # ----------------------------------------------------------------------
@@ -431,6 +494,13 @@ class SecureServer:
     @jax.named_scope("step5_fold")
     def aggregate(name: str, U, ctx: AggregationContext):
         return aggregate(name, U, ctx)
+
+    @staticmethod
+    @jax.named_scope("step5_fold")
+    def aggregate_leaves(name: str, updates, ctx: AggregationContext):
+        """:meth:`aggregate` through the rule's leaf form: stacked update
+        pytree -> (delta pytree, logs)."""
+        return _LEAF_FORMS[name].fn(updates, ctx)
 
     @staticmethod
     def streaming_aggregator(name: str, ctx: AggregationContext):

@@ -26,6 +26,14 @@ Up to CLIENT_TILE clients the block is the whole client axis and the
 result is the single ``sum(u * w, axis=0)`` of the untiled kernel.
 Neither axis is padded in HBM: rows past N are zeroed in-kernel, and
 columns past D only reach output columns that are sliced away.
+
+``masked_agg_leaf_kernel`` folds one parameter leaf as the vmapped SGD
+wrote it, viewed as ``(N, R, L)`` (kernels/similarity.py's leaf view):
+grid (R/tr, L/lc, N), the client axis trailing and sequential, so the
+(tr, lc) output block is the accumulator that stays in VMEM while each
+client's block of the leaf is weighted into it.  The delta comes out in
+the parameter's own layout, so no (N, D) row matrix is built and none is
+unraveled.  Edge blocks only reach output elements that are dropped.
 """
 from __future__ import annotations
 
@@ -34,6 +42,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .similarity import LEAF_BLOCK_BYTES, leaf_tiles
 
 DEFAULT_CHUNK = 16 * 1024
 CLIENT_TILE = 32        # a whole sublane tile of f32, bf16 and int8 rows
@@ -108,3 +118,30 @@ def masked_agg_kernel(u, mask, *, chunk: int = DEFAULT_CHUNK,
     return masked_agg_update_kernel(
         u, w, jnp.zeros((u.shape[1],), jnp.float32), chunk=chunk,
         interpret=interpret)
+
+
+def _leaf_kernel(w_ref, u_ref, out_ref):
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    out_ref[...] += u_ref[...].astype(jnp.float32) * w_ref[...]
+
+
+def masked_agg_leaf_kernel(u, w, *, block_bytes: int = LEAF_BLOCK_BYTES,
+                           interpret: bool = False):
+    """u: (N, R, L) leaf view; w: (N,) per-client weights, the mask over
+    max(|kept|, 1) that ``masked_agg_kernel`` folds -> (R, L) fp32
+    ``sum_i w_i * u_i``."""
+    n, r, l = u.shape
+    tr, lc = leaf_tiles(r, l, (u.dtype,), block_bytes)
+    return pl.pallas_call(
+        _leaf_kernel,
+        grid=(pl.cdiv(r, tr), pl.cdiv(l, lc), n),
+        in_specs=[pl.BlockSpec((None, 1, 1), lambda i, j, k: (k, 0, 0)),
+                  pl.BlockSpec((None, tr, lc), lambda i, j, k: (k, i, j))],
+        out_specs=pl.BlockSpec((tr, lc), lambda i, j, k: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((r, l), jnp.float32),
+        name="masked_agg",
+        interpret=interpret,
+    )(w.astype(jnp.float32).reshape(n, 1, 1), u)

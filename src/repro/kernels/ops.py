@@ -89,6 +89,103 @@ def diversefl_step45(u, g, cfg, chunk: int = _sim.DEFAULT_CHUNK):
     return delta, mask, (dot, zz, gg)
 
 
+# ----------------------------------------------------------------------
+# Step 4+5 on the stacked per-client pytrees the vmapped SGD wrote: each
+# leaf (N, *s) is read in place as its (N, R, L) view (a bitcast wherever
+# s[-2] tiles the sublanes), so no (N, D) row matrix is built.  Leaves
+# with at most one axis per client already are rows: they are joined into
+# one small (N, D_rows) block for the (n, D) kernels.  Statistics and
+# fold are float32, every leaf and every client included.
+# ----------------------------------------------------------------------
+
+def _leaf_view(u):
+    """(N, *s) -> (N, prod(s[:-1]), s[-1])."""
+    return u.reshape(u.shape[0], -1, u.shape[-1])
+
+
+def _row_leaves(leaves):
+    """Indices of the leaves that are rows already: at most one axis
+    after the client axis."""
+    return [i for i, u in enumerate(leaves) if u.ndim <= 2]
+
+
+def _join_rows(leaves, rows):
+    n = leaves[0].shape[0]
+    with jax.named_scope("flatten"):
+        return jnp.concatenate(
+            [leaves[i].reshape(n, -1).astype(jnp.float32) for i in rows],
+            axis=1)
+
+
+def _leaf_stats(z_tree, g_tree):
+    zs, gs = jax.tree.leaves(z_tree), jax.tree.leaves(g_tree)
+    rows = _row_leaves(zs)
+    parts = []
+    if rows:
+        parts.append(_sim.similarity_kernel(
+            _join_rows(zs, rows), _join_rows(gs, rows),
+            interpret=_interpret()))
+    parts += [_sim.similarity_leaf_kernel(_leaf_view(z), _leaf_view(g),
+                                          interpret=_interpret())
+              for z, g in zip(zs, gs) if z.ndim > 2]
+    s = functools.reduce(jnp.add, parts)
+    return s[:, 0], s[:, 1], s[:, 2]
+
+
+def _leaf_fold(u_tree, mask):
+    leaves, treedef = jax.tree.flatten(u_tree)
+    m = mask.astype(jnp.float32)
+    w = m / jnp.maximum(m.sum(), 1.0)
+    out = [None] * len(leaves)
+    rows = _row_leaves(leaves)
+    if rows:
+        u = _join_rows(leaves, rows)
+        flat = _ma.masked_agg_update_kernel(
+            u, w, jnp.zeros((u.shape[1],), jnp.float32),
+            interpret=_interpret())
+        off = 0
+        for i in rows:
+            size = leaves[i][0].size
+            out[i] = flat[off:off + size].reshape(leaves[i].shape[1:])
+            off += size
+    for i, u in enumerate(leaves):
+        if u.ndim > 2:
+            out[i] = _ma.masked_agg_leaf_kernel(
+                _leaf_view(u), w, interpret=_interpret()
+            ).reshape(u.shape[1:])
+    return jax.tree.unflatten(treedef, out)
+
+
+@jax.jit
+def similarity_stats_leaves(z_tree, g_tree):
+    """Stacked update and guide pytrees -> per-client (dot, ||z||^2,
+    ||g||^2), each (N,) fp32, summed over the leaves."""
+    with jax.named_scope("step4_filter"):
+        return _leaf_stats(z_tree, g_tree)
+
+
+@jax.jit
+def masked_aggregate_leaves(u_tree, mask):
+    """Stacked update pytree + (N,) mask -> the masked mean (Eq. 6) as a
+    pytree of fp32 leaves in the parameters' shapes."""
+    with jax.named_scope("step5_fold"):
+        return _leaf_fold(u_tree, mask)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def diversefl_step45_leaves(u_tree, g_tree, cfg):
+    """:func:`diversefl_step45` on the stacked pytrees: (delta pytree,
+    keep mask (N,), (dot, ||z||^2, ||g||^2)), one pass over each update
+    and guide leaf for the statistics and one over each update leaf for
+    the fold."""
+    with jax.named_scope("step4_filter"):
+        dot, zz, gg = _leaf_stats(u_tree, g_tree)
+        mask = diversefl_mask(dot, zz, gg, cfg)
+    with jax.named_scope("step5_fold"):
+        delta = _leaf_fold(u_tree, mask)
+    return delta, mask, (dot, zz, gg)
+
+
 @functools.partial(jax.jit, static_argnames=("f", "chunk"))
 def robust_aggregate(u, f: int = 0, chunk: int = _ra.DEFAULT_CHUNK):
     """(N, D) -> (median (D,), trimmed_mean (D,))."""

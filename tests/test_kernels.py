@@ -95,6 +95,107 @@ def test_diversefl_step45_fused_matches_reference():
 
 
 # ----------------------------------------------------------------------
+# leaf forms: Step 4+5 on one stacked leaf viewed as (N, R, L)
+# ----------------------------------------------------------------------
+
+# R not a multiple of 8; L below 128 or not a multiple of it
+LEAF_SHAPES = [(23, 40, 256), (23, 27, 64), (23, 4608 // 64, 512),
+               (1, 16, 128), (5, 3, 10), (3, 20, 300)]
+# the default blocks, and blocks small enough that row and column edge
+# blocks are masked in-kernel
+LEAF_BLOCKS = [None, 4096]
+
+
+def _leaf_operands(shape, seed=0):
+    rng = np.random.default_rng(seed + sum(shape))
+    z = jnp.asarray(rng.normal(size=shape).astype(np.float32))
+    g = jnp.asarray(rng.normal(size=shape).astype(np.float32))
+    mask = jnp.asarray(rng.integers(0, 2, size=shape[0]).astype(bool))
+    return z, g, mask
+
+
+def _blocks(block_bytes):
+    return {} if block_bytes is None else {"block_bytes": block_bytes}
+
+
+@pytest.mark.parametrize("block_bytes", LEAF_BLOCKS)
+@pytest.mark.parametrize("shape", LEAF_SHAPES)
+def test_similarity_leaf_matches_row_kernel(shape, block_bytes):
+    from repro.core.diversefl import similarity_stats_matrix
+    from repro.kernels import similarity
+    z, g, _ = _leaf_operands(shape)
+    got = similarity.similarity_leaf_kernel(z, g, interpret=True,
+                                            **_blocks(block_bytes))
+    zf, gf = z.reshape(shape[0], -1), g.reshape(shape[0], -1)
+    np.testing.assert_allclose(got, ops.similarity_stats(zf, gf),
+                               rtol=1e-5, atol=1e-4)
+    np.testing.assert_allclose(
+        got, jnp.stack(similarity_stats_matrix(zf, gf), -1),
+        rtol=1e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("block_bytes", LEAF_BLOCKS)
+@pytest.mark.parametrize("shape", LEAF_SHAPES)
+def test_masked_agg_leaf_matches_row_kernel(shape, block_bytes):
+    from repro.core.diversefl import masked_mean_flat
+    from repro.kernels import masked_agg
+    u, _, mask = _leaf_operands(shape)
+    m = mask.astype(jnp.float32)
+    got = masked_agg.masked_agg_leaf_kernel(
+        u, m / jnp.maximum(m.sum(), 1.0), interpret=True,
+        **_blocks(block_bytes))
+    assert got.shape == shape[1:] and got.dtype == jnp.float32
+    uf = u.reshape(shape[0], -1)
+    np.testing.assert_allclose(got.reshape(-1), ops.masked_aggregate(uf, mask),
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(got.reshape(-1), masked_mean_flat(uf, mask),
+                               rtol=1e-5, atol=1e-6)
+
+
+def test_masked_agg_leaf_empty_mask_yields_zero():
+    u = jnp.ones((4, 27, 64))
+    got = ops.masked_aggregate_leaves({"w": u, "b": u[:, 0]},
+                                      jnp.zeros((4,), bool))
+    np.testing.assert_array_equal(np.asarray(got["w"]), np.zeros((27, 64)))
+    np.testing.assert_array_equal(np.asarray(got["b"]), np.zeros(64))
+
+
+def _tree(n, seed):
+    """A model-like stack: conv, dense and narrow leaves, and leaves that
+    are rows already (biases, a scalar)."""
+    rng = np.random.default_rng(seed)
+    shapes = {"c": (3, 3, 3, 16), "w": (40, 256), "h": (64, 10),
+              "b": (256,), "s": ()}
+    return {k: jnp.asarray(rng.normal(size=(n,) + s).astype(np.float32))
+            for k, s in shapes.items()}
+
+
+@pytest.mark.parametrize("n", [1, 9])
+def test_diversefl_step45_leaves_matches_rows(n):
+    from repro.core.aggregators import flatten_updates
+    from repro.core.diversefl import DiverseFLConfig
+    g = _tree(n, 0)
+    z = jax.tree.map(lambda a: a + 0.3 * a[::-1], g)
+    z["w"] = z["w"].at[0].multiply(-1.0)
+    cfg = DiverseFLConfig()
+    delta, mask, stats = ops.diversefl_step45_leaves(z, g, cfg)
+    zf, unravel = flatten_updates(z)
+    gf, _ = flatten_updates(g)
+    want_delta, want_mask, want_stats = ops.diversefl_step45(zf, gf, cfg)
+    np.testing.assert_array_equal(np.asarray(mask), np.asarray(want_mask))
+    np.testing.assert_allclose(jnp.stack(stats, -1),
+                               jnp.stack(want_stats, -1), rtol=1e-5,
+                               atol=1e-4)
+    for k, leaf in unravel(want_delta).items():
+        assert delta[k].shape == leaf.shape, k
+        np.testing.assert_allclose(delta[k], leaf, rtol=1e-5, atol=1e-6,
+                                   err_msg=k)
+    np.testing.assert_allclose(
+        jnp.stack(ops.similarity_stats_leaves(z, g), -1),
+        jnp.stack(stats, -1), rtol=1e-6)
+
+
+# ----------------------------------------------------------------------
 # robust aggregation
 # ----------------------------------------------------------------------
 

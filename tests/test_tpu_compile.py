@@ -70,6 +70,17 @@ def _flash(b, h, k, s, dh, window=None):
                 ((b, k, s, dh), jnp.bfloat16)]
 
 
+def _leaf(form, r, l, n=23):
+    """A leaf form on one stacked leaf viewed as (n, r, l)."""
+    if form == "similarity":
+        fn, specs = similarity.similarity_leaf_kernel, [
+            ((n, r, l), jnp.float32)] * 2
+    else:
+        fn, specs = masked_agg.masked_agg_leaf_kernel, [
+            ((n, r, l), jnp.float32), ((n,), jnp.float32)]
+    return lambda dv: (fn, specs)
+
+
 # name -> make(vgg11_d) -> (fn, [(shape, dtype), ...])
 CASES = {
     # dense DiverseFL Step 4 over the paper's N=23 VGG-11 federation
@@ -92,6 +103,11 @@ CASES = {
         [((1024, (1 << 20) + 3), jnp.int8),
          ((1024, -(-((1 << 20) + 3) // 128)), jnp.float32),
          ((1024,), jnp.float32), (((1 << 20) + 3,), jnp.float32)]),
+    # leaf forms on VGG-11's stacked leaves: the 4096x4096 head, a
+    # 3x3x512x512 conv, the 4096x10 output layer, the first conv
+    **{f"{form}_leaf_vgg11_n23_{r}x{l}": _leaf(form, r, l)
+       for form in ("similarity", "masked_agg")
+       for r, l in ((4096, 4096), (4608, 512), (4096, 10), (27, 64))},
     "robust_agg_vgg11_n23": lambda dv: (
         lambda u: robust_agg.robust_agg_kernel(u, 5),
         [((23, dv), jnp.float32)]),
