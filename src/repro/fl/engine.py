@@ -325,6 +325,9 @@ def make_round_body(model, fed, cfg, *, client_chunk: Optional[int] = None):
     telemetry.event("dense_layout", aggregator=cfg.aggregator,
                     layout="leaves" if leaves else "rows",
                     reason=rows_reason)
+    share = getattr(model, "expert_share", None)
+    if share is not None:
+        telemetry.event("expert_share", **share)
     if entry.needs_guides:
         # Unseal + cache the guide batches *eagerly*, outside any trace:
         # building the device-side cache under jit/scan tracing would

@@ -249,15 +249,28 @@ def make_eval_fn(model, fed, cfg):
     bitwise (integer-count metrics are association-free).  The metric
     set is static per config: main-task + backdoor accuracy appear under
     a backdoor attack, detection TPR/FPR and the C1·C2 criterion logs
-    whenever the aggregator emits a keep-mask.
+    whenever the aggregator emits a keep-mask, and a model with a
+    dropless expert share adds its routing counters from the same eval
+    forward (``ZooModel.apply_with_routing``).
     """
     acfg = cfg.attack
     bd = fed.backdoor_eval(acfg) if acfg.kind == "backdoor" else None
     main_mask = None if bd is None else ~bd.src
 
+    share = getattr(model, "expert_share", None)
+    routing = model.apply_with_routing if share and share["dropless"] \
+        else None
+
     @jax.named_scope("eval")
     def eval_fn(params, logs):
-        m = {"acc": accuracy(model, params, fed.test_x, fed.test_y)}
+        if routing is None:
+            m = {"acc": accuracy(model, params, fed.test_x, fed.test_y)}
+        else:
+            # one forward gives the accuracy and the routing counters
+            lg, counters = routing(params, fed.test_x)
+            m = {"acc": _ratio(jnp.sum(jnp.argmax(lg, -1) == fed.test_y),
+                               jnp.asarray(fed.test_y.shape[0]), 0.0),
+                 **counters}
         if bd is not None:
             m["main_acc"] = masked_accuracy(model, params, fed.test_x,
                                             fed.test_y, main_mask)

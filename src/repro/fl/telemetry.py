@@ -26,7 +26,8 @@ first-class requirement.  This module is that trail, in four parts:
   * **Stage scopes** — :data:`SCOPES` names the stages of a round; each
     is a ``jax.named_scope`` in the function that does the work, so every
     compiled op carries its stage in its ``op_name`` metadata and a
-    device trace can be split by stage (DESIGN.md §11).
+    device trace can be split by stage (DESIGN.md §11); inside them
+    :data:`LAYER_SCOPES` names model layers (MLA, routed experts).
   * **On-device round telemetry** — :func:`make_round_telemetry_fn`
     builds the per-round telemetry block the engine accumulates
     *inside* the scan (C1/C2 pass counts, tagged-client popcount,
@@ -67,6 +68,11 @@ SCHEMA_VERSION = 1
 # the names are a contract with its readers and do not change.
 SCOPES = ("client_sgd", "attack", "flatten", "guide_sgd", "step4_filter",
           "step5_fold", "eval")
+
+# The model layers the program names inside those stages (DESIGN.md
+# §12): an op under one of these still belongs to its stage, so a split
+# by stage does not move; a per-layer reading looks for the name itself.
+LAYER_SCOPES = ("mla", "routed_experts")
 
 # The hash chain's genesis digest: the first entry commits to this.
 GENESIS = "0" * 64

@@ -79,9 +79,39 @@ class ZooModel:
     def apply(self, params, x):
         """Last-position next-token logits, (B, vocab_size) f32 — the
         classification head the metrics stack scores."""
-        out = models.apply(params, self.cfg, _as_tokens(x))
+        return self._forward(params, _as_tokens(x))[0]
+
+    def _forward(self, params, tok):
+        out = models.apply(params, self.cfg, tok)
         lg = models.logits(params, self.cfg, out["hidden"][:, -1:, :])
-        return lg[:, 0, :self.cfg.vocab_size]
+        return lg[:, 0, :self.cfg.vocab_size], out["aux"]
+
+    @property
+    def expert_share(self):
+        """What the expert layers hold and how they dispatch, for the
+        engine's ``expert_share`` event; None without routed experts."""
+        c = self.cfg
+        if not c.n_experts:
+            return None
+        return {"held": c.n_held_experts, "routed": c.n_experts,
+                "top_k": c.top_k, "dropless": c.dropless,
+                "grouped": "megablox.gmm" if c.dropless else None}
+
+    def apply_with_routing(self, params, x):
+        """``apply`` plus the routing counters of a dropless expert
+        share: ``held_slot_share``, the share of the token
+        slots of every expert layer that land on held experts, and
+        ``held_load_peak``, the largest held expert's load over the mean
+        held load, the loads summed over the layers."""
+        tok = _as_tokens(x)
+        lg, (_, load) = self._forward(params, tok)
+        load = load.astype(jnp.float32)
+        layers = self.cfg.n_groups * sum(f == "moe"
+                                         for _, f in self.cfg.layout)
+        slots = tok.size * self.cfg.top_k * layers
+        return lg, {"held_slot_share": load.sum() / slots,
+                    "held_load_peak": load.max() / jnp.maximum(
+                        load.mean(), 1.0)}
 
     def loss(self, params, x, y, l2: float = 0.0):
         """Full-sequence LM loss over ``concat(x, y)`` — every position

@@ -1,9 +1,11 @@
-"""Attention: GQA/MQA self-attention (full / sliding-window), cross-attention,
-blockwise (flash-style) long-sequence path, and single-token decode with a
-KV cache (ring buffer for sliding-window layers).
+"""Attention: GQA/MQA self-attention (full / sliding-window), multi-head
+latent attention (DeepSeek-V2 MLA), cross-attention, blockwise
+(flash-style) long-sequence path, and single-token decode with a KV cache
+(ring buffer for sliding-window layers).
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -11,8 +13,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..sharding import shard
-from .config import ModelConfig
-from .layers import dense_init
+from .config import ModelConfig, Yarn
+from .layers import dense_init, rms_norm
 
 NEG_INF = -2.0 ** 30
 
@@ -21,16 +23,40 @@ NEG_INF = -2.0 ** 30
 # RoPE
 # ----------------------------------------------------------------------
 
-def rope(x, positions, theta):
-    """x: (B, S, H, dh); positions: (B, S) int32."""
+def rope(x, positions, theta, freq=None):
+    """x: (B, S, H, dh); positions: (B, S) int32; ``freq`` (dh/2,) the
+    rotary frequencies (default ``theta ** (-2i/dh)``)."""
     dh = x.shape[-1]
     half = dh // 2
-    freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if freq is None:
+        freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     ang = positions[..., None].astype(jnp.float32) * freq  # (B, S, half)
     sin, cos = jnp.sin(ang)[:, :, None, :], jnp.cos(ang)[:, :, None, :]
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            axis=-1).astype(x.dtype)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(dim: int, theta: float, y: Yarn):
+    """YaRN frequencies of ``dim/2`` rotary pairs, as HF DeepSeek-V2's
+    rotary embedding computes them: pairs below the correction range (the
+    fast ones) keep their base frequency, pairs above it are divided by
+    ``factor``, and those inside it blend the two linearly."""
+    def corr(rot):
+        return dim * math.log(y.original_max_position / (rot * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(corr(y.beta_fast)), 0)
+    high = min(math.ceil(corr(y.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    i = jnp.arange(dim // 2, dtype=jnp.float32)
+    base = theta ** (-2.0 * i / dim)
+    ramp = jnp.clip((i - low) / (high - low), 0.0, 1.0)
+    return base / y.factor * ramp + base * (1.0 - ramp)
 
 
 # ----------------------------------------------------------------------
@@ -51,20 +77,24 @@ def make_attn_params(key, cfg: ModelConfig, cross: bool = False):
 # Core softmax attention on explicit q, k, v
 # ----------------------------------------------------------------------
 
-def _sdpa(q, k, v, mask, softcap=None):
-    """q: (B,Sq,H,dh)  k,v: (B,Sk,K,dh)  mask: broadcastable (B,1,Sq,Sk) bool."""
+def _sdpa(q, k, v, mask, softcap=None, scale=None):
+    """q, k: (B,Sq|Sk,H|K,dh)  v: (B,Sk,K,dv)  mask: broadcastable
+    (B,1,Sq,Sk) bool; scores scaled by ``scale`` (default dh**-0.5)."""
     B, Sq, H, dh = q.shape
     K = k.shape[2]
     g = H // K
     qf = q.reshape(B, Sq, K, g, dh).astype(jnp.float32)
     s = jnp.einsum("bqkgd,bskd->bkgqs", qf, k.astype(jnp.float32))
-    s = s / jnp.sqrt(dh).astype(jnp.float32)
+    if scale is None:
+        s = s / jnp.sqrt(dh).astype(jnp.float32)
+    else:
+        s = s * jnp.float32(scale)
     if softcap:
         s = jnp.tanh(s / softcap) * softcap
     s = jnp.where(mask[:, :, None, :, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgqs,bskd->bqkgd", p, v.astype(jnp.float32))
-    return o.reshape(B, Sq, H, dh).astype(v.dtype)
+    return o.reshape(B, Sq, H, v.shape[-1]).astype(v.dtype)
 
 
 def _causal_mask(q_pos, k_pos, window):
@@ -75,7 +105,8 @@ def _causal_mask(q_pos, k_pos, window):
     return m
 
 
-def _blockwise(q, k, v, q_pos, k_pos, window, chunk, softcap=None):
+def _blockwise(q, k, v, q_pos, k_pos, window, chunk, softcap=None,
+               scale=None):
     """Memory-efficient attention: scan over q chunks (the XLA 'flash' path).
 
     For sliding-window layers each q chunk only loads a (chunk+window) slice
@@ -104,12 +135,12 @@ def _blockwise(q, k, v, q_pos, k_pos, window, chunk, softcap=None):
         else:
             ki, vi, kpi = k, v, k_pos
         mask = _causal_mask(pi, kpi, window) & (pi[:, None, :, None] >= 0)
-        oi = _sdpa(qi, ki, vi, mask, softcap)
+        oi = _sdpa(qi, ki, vi, mask, softcap, scale)
         return carry, oi
 
     _, out = jax.lax.scan(body, None,
                           (jnp.arange(n_chunks), (qc, pc)))
-    out = out.swapaxes(0, 1).reshape(B, n_chunks * chunk, H, dh)
+    out = out.swapaxes(0, 1).reshape(B, n_chunks * chunk, H, v.shape[-1])
     return out[:, :S]
 
 
@@ -165,6 +196,70 @@ def self_attention(x, p, cfg: ModelConfig, positions, window=None,
 
     out = o.reshape(B, S, H * dh) @ p["wo"]
     return out, new_cache
+
+
+# ----------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 §2.1)
+# ----------------------------------------------------------------------
+
+def make_mla_params(key, cfg: ModelConfig):
+    ks = jax.random.split(key, 4)
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    return {"wq": dense_init(ks[0], (d, h * (dn + dr)), cfg.param_dtype),
+            "wkv_a": dense_init(ks[1], (d, r + dr), cfg.param_dtype),
+            "kv_norm": {"scale": jnp.zeros((r,), cfg.param_dtype)},
+            "wkv_b": dense_init(ks[2], (r, h * (dn + dv)), cfg.param_dtype),
+            "wo": dense_init(ks[3], (h * dv, d), cfg.param_dtype,
+                             fan_in=h * dv)}
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """(qk head dim)**-0.5, times mscale**2 under YaRN."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if cfg.yarn is not None and cfg.yarn.mscale_all_dim:
+        m = yarn_mscale(cfg.yarn.factor, cfg.yarn.mscale_all_dim)
+        scale *= m * m
+    return scale
+
+
+def mla_attention(x, p, cfg: ModelConfig, positions):
+    """Causal MLA over the whole sequence (no q LoRA):
+    ``q = x Wq``; ``[c | k_pe] = x Wkv_a``, ``c`` RMS-normed; ``[k_nope |
+    v] = c Wkv_b``; ``k = [k_nope, rope(k_pe)]`` with ``k_pe`` shared by
+    all heads; ``q = [q_nope, rope(q_pe)]``; ``o = softmax(q k^T s) v``
+    then ``Wo``.  Rotary pairs are split in halves, where HF stores
+    them interleaved (a relabelling of weight columns)."""
+    B, S, _ = x.shape
+    H, r = cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    with jax.named_scope("mla"):
+        q = (x @ p["wq"]).reshape(B, S, H, dn + dr)
+        q = shard(q, P(None, None, "model", None))
+        ckv = x @ p["wkv_a"]
+        c = rms_norm(ckv[..., :r], p["kv_norm"]["scale"])
+        kv = (c @ p["wkv_b"]).reshape(B, S, H, dn + dv)
+        freq = None if cfg.yarn is None else yarn_freqs(dr, cfg.rope_theta,
+                                                        cfg.yarn)
+        q_pe = rope(q[..., dn:], positions, cfg.rope_theta, freq)
+        k_pe = rope(ckv[:, :, None, r:], positions, cfg.rope_theta, freq)
+        if cfg.yarn is not None:
+            cs = yarn_mscale(cfg.yarn.factor, cfg.yarn.mscale) / yarn_mscale(
+                cfg.yarn.factor, cfg.yarn.mscale_all_dim)
+            if cs != 1.0:
+                q_pe, k_pe = q_pe * cs, k_pe * cs
+        q = jnp.concatenate([q[..., :dn], q_pe], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(k_pe, (B, S, H, dr))], axis=-1)
+        v = kv[..., dn:]
+        scale = mla_softmax_scale(cfg)
+        if S <= cfg.attn_direct_max:
+            o = _sdpa(q, k, v, _causal_mask(positions, positions, None),
+                      cfg.logit_softcap, scale)
+        else:
+            o = _blockwise(q, k, v, positions, positions, None,
+                           cfg.attn_chunk, cfg.logit_softcap, scale)
+        return o.reshape(B, S, H * dv) @ p["wo"]
 
 
 # ----------------------------------------------------------------------

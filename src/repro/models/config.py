@@ -10,6 +10,8 @@ Layers are described by a repeating ``layout`` *group*: a tuple of
 n_groups * len(layout)``.  Mixers:
 
   - ``attn``    causal self attention (GQA; ``window`` applies if set)
+  - ``mla``     multi-head latent attention (DeepSeek-V2): a low-rank
+                latent KV and a decoupled rotary key shared by all heads
   - ``swa``     sliding-window causal self attention (forces ``window``)
   - ``mamba``   Mamba-1 selective-scan block
   - ``xattn``   cross-attention block (VLM image layers, attends to
@@ -19,7 +21,11 @@ n_groups * len(layout)``.  Mixers:
 
 FFN kinds: ``mlp`` (gated or plain), ``moe`` (fine-grained, optional
 shared experts) or ``none`` (block has no separate FFN, e.g. Mamba-only
-stacks).
+stacks).  An expert layer may hold a share of the experts
+(``n_held_experts`` of ``n_experts``, expert parallelism): it routes over
+all of them and computes its own experts' part.  ``capacity_factor=None``
+makes the layer dropless (grouped products over the rows routed to the
+held experts) instead of dropping by capacity.
 """
 from __future__ import annotations
 
@@ -30,6 +36,17 @@ from typing import Optional, Tuple
 Mixer = str
 Ffn = str
 LayoutEntry = Tuple[Mixer, Ffn]
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rotary scaling (arXiv:2309.00071) as DeepSeek-V2 sets it."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,14 +66,27 @@ class ModelConfig:
     rope_theta: float = 10_000.0
     window: Optional[int] = None            # sliding-window size for swa mixers
     logit_softcap: Optional[float] = None
+    embed_scale: Optional[float] = None     # None: sqrt(d_model) for rmsnorm models
+    yarn: Optional[Yarn] = None             # rotary scaling of the mla mixer
+
+    # --- MLA (mixer "mla") ---
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # --- MoE ---
     n_experts: int = 0
     top_k: int = 0
     n_shared_experts: int = 0
     d_expert: Optional[int] = None          # fine-grained expert hidden dim (defaults d_ff)
-    capacity_factor: float = 1.25
+    capacity_factor: Optional[float] = 1.25  # None: dropless
     router_aux_coef: float = 0.01
+    router_dtype: str = "float32"           # stored router; its logits are f32
+    norm_topk_prob: bool = True             # renormalise the top-k gates
+    routed_scale: float = 1.0               # routed gates' scaling factor
+    n_held_experts: Optional[int] = None    # experts this layer holds (default all)
+    held_share: int = 0                     # holds experts [j*n_held, (j+1)*n_held)
 
     # --- SSM (Mamba-1) ---
     ssm_state: int = 16
@@ -100,11 +130,34 @@ class ModelConfig:
             raise ValueError(f"{self.name}: swa mixer requires window")
         if any(f == "moe" for _, f in self.layout) and self.n_experts <= 0:
             raise ValueError(f"{self.name}: moe layout requires n_experts > 0")
+        if self.n_held_experts is None:
+            object.__setattr__(self, "n_held_experts", self.n_experts)
+        if self.n_held_experts < self.n_experts and self.capacity_factor \
+                is not None:
+            raise ValueError(f"{self.name}: an expert share is dropless "
+                             f"(capacity_factor=None)")
+        if (self.held_share + 1) * self.n_held_experts > self.n_experts:
+            raise ValueError(f"{self.name}: expert share {self.held_share} "
+                             f"lies outside the {self.n_experts} experts")
+        if any(m == "mla" for m, _ in self.layout) and not (
+                self.kv_lora_rank and self.qk_rope_head_dim
+                and self.v_head_dim):
+            raise ValueError(f"{self.name}: mla mixer requires kv_lora_rank, "
+                             f"qk_rope_head_dim and v_head_dim")
 
     # ------------------------------------------------------------------
     @property
     def n_groups(self) -> int:
         return (self.n_layers - self.first_k_dense) // len(self.layout)
+
+    @property
+    def dropless(self) -> bool:
+        return self.n_experts > 0 and self.capacity_factor is None
+
+    @property
+    def prelude_entry(self) -> LayoutEntry:
+        """The leading dense layers: the layout's first mixer, a dense MLP."""
+        return (self.layout[0][0], "mlp")
 
     @property
     def d_inner(self) -> int:
@@ -143,7 +196,7 @@ class ModelConfig:
         seq-sharded).  ``xattn`` attends to a fixed-length embedding
         sequence; ``attn_x`` contains full causal self attention."""
         def is_full_attn(m):
-            return (m in ("attn", "attn_x")) and self.window is None
+            return (m in ("attn", "attn_x", "mla")) and self.window is None
 
         full = sum(is_full_attn(m) for m, _ in self.layout)
         mamba = sum(m == "mamba" for m, _ in self.layout)

@@ -11,7 +11,8 @@ Params layout::
 
 Layer stacking uses ``jax.lax.scan`` over groups so compile time and HLO
 size are independent of depth (61-layer / 100-layer configs lower in
-seconds).  Activation checkpointing (``cfg.remat``) wraps the group body.
+seconds).  Activation checkpointing (``cfg.remat``) wraps the group body
+and each leading dense block.
 """
 from __future__ import annotations
 
@@ -24,12 +25,12 @@ from jax.sharding import PartitionSpec as P
 
 from ..sharding import shard
 from .attention import (cross_attention, make_attn_params, make_cross_kv,
-                        self_attention)
+                        make_mla_params, mla_attention, self_attention)
 from .config import ModelConfig
 from .layers import (apply_mlp, apply_norm, dense_init, make_mlp_params,
                      make_norm_params)
 from .mamba import init_mamba_cache, make_mamba_params, mamba_mixer
-from .moe import apply_moe, make_moe_params
+from .moe import apply_expert_share, apply_moe, make_moe_params
 
 
 # ----------------------------------------------------------------------
@@ -42,6 +43,8 @@ def _make_block_params(key, cfg: ModelConfig, entry, force_mlp=False):
     p = {"ln1": make_norm_params(ks[0], cfg)}
     if mixer in ("attn", "swa"):
         p["attn"] = make_attn_params(ks[1], cfg)
+    elif mixer == "mla":
+        p["mla"] = make_mla_params(ks[1], cfg)
     elif mixer == "mamba":
         p["mamba"] = make_mamba_params(ks[1], cfg)
     elif mixer == "xattn":
@@ -78,7 +81,7 @@ def init(key, cfg: ModelConfig):
     if cfg.first_k_dense:
         pk = jax.random.split(ks[2], cfg.first_k_dense)
         params["prelude"] = [
-            _make_block_params(pk[i], cfg, ("attn", "mlp"))
+            _make_block_params(pk[i], cfg, cfg.prelude_entry)
             for i in range(cfg.first_k_dense)]
 
     gk = jax.random.split(ks[3], cfg.n_groups)
@@ -105,13 +108,34 @@ def init(key, cfg: ModelConfig):
 # Blocks
 # ----------------------------------------------------------------------
 
+def _zero_aux(cfg: ModelConfig):
+    """What a block adds up besides its output: the balance loss, and
+    under a dropless expert layer the slots each held expert served."""
+    loss = jnp.zeros((), jnp.float32)
+    if cfg.dropless:
+        return loss, jnp.zeros((cfg.n_held_experts,), jnp.int32)
+    return loss
+
+
+def _aux_loss(aux):
+    return aux[0] if isinstance(aux, tuple) else aux
+
+
+def _add_aux(a, b):
+    return jax.tree.map(jnp.add, a, b)
+
+
 def _run_block(x, bp, entry, cfg: ModelConfig, positions, cross_emb,
                cache, cache_index):
     mixer, ffn = entry
-    aux = jnp.zeros((), jnp.float32)
+    aux = _zero_aux(cfg)
     h = apply_norm(x, bp["ln1"], cfg)
     new_cache = None
-    if mixer in ("attn", "swa"):
+    if mixer == "mla":
+        if cache is not None:
+            raise NotImplementedError("mla has no decode cache")
+        o = mla_attention(h, bp["mla"], cfg, positions)
+    elif mixer in ("attn", "swa"):
         window = cfg.window if mixer == "swa" else None
         o, kv = self_attention(h, bp["attn"], cfg, positions, window,
                                cache=cache, cache_index=cache_index)
@@ -141,7 +165,10 @@ def _run_block(x, bp, entry, cfg: ModelConfig, positions, cross_emb,
 
     if ffn in ("mlp", "moe") or (ffn == "none" and "mlp" in bp):
         h = apply_norm(x, bp["ln2"], cfg)
-        if "moe" in bp:
+        if "moe" in bp and cfg.dropless:
+            f, loss, load = apply_expert_share(h, bp["moe"], cfg)
+            aux = (loss, load)
+        elif "moe" in bp:
             f, aux = apply_moe(h, bp["moe"], cfg)
         else:
             f = apply_mlp(h, bp["mlp"], cfg)
@@ -160,7 +187,7 @@ def _scan_groups(x, groups, cfg: ModelConfig, positions, cross_emb,
             xc, nc, a = _run_block(xc, gp[li], entry, cfg, positions,
                                    cross_emb, c_in, cache_index)
             new_gc.append(nc)
-            aux = aux + a
+            aux = _add_aux(aux, a)
         ys = tuple(new_gc) if (decode or collect_cache) else None
         return (xc, aux), ys
 
@@ -169,7 +196,7 @@ def _scan_groups(x, groups, cfg: ModelConfig, positions, cross_emb,
         body = jax.checkpoint(
             gfn, policy=jax.checkpoint_policies.nothing_saveable)
     (x, aux), new_cache = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), (groups, cache))
+        body, (x, _zero_aux(cfg)), (groups, cache))
     return x, aux, new_cache
 
 
@@ -209,15 +236,23 @@ def _encode(params, cfg: ModelConfig, enc_emb):
 # Forward / prefill
 # ----------------------------------------------------------------------
 
+def _embed(params, cfg: ModelConfig, tokens):
+    x = params["embed"][tokens].astype(cfg.dtype)
+    if cfg.embed_scale is not None:
+        return x * jnp.asarray(cfg.embed_scale, cfg.dtype)
+    if cfg.norm == "rmsnorm":
+        x = x * jnp.sqrt(cfg.d_model).astype(cfg.dtype)
+    return x
+
+
 def apply(params, cfg: ModelConfig, tokens, *, enc_emb=None, cross_emb=None,
           positions=None, want_cache=False):
-    """Full-sequence forward.  Returns dict(hidden, aux, cache?)."""
+    """Full-sequence forward.  Returns dict(hidden, aux, cache?); ``aux``
+    as :func:`_zero_aux` shapes it, summed over the layers."""
     B, S = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    x = params["embed"][tokens].astype(cfg.dtype)
-    if cfg.norm == "rmsnorm":
-        x = x * jnp.sqrt(cfg.d_model).astype(cfg.dtype)
+    x = _embed(params, cfg, tokens)
 
     if cfg.is_enc_dec:
         assert enc_emb is not None, "enc-dec arch needs enc_emb"
@@ -225,18 +260,23 @@ def apply(params, cfg: ModelConfig, tokens, *, enc_emb=None, cross_emb=None,
     elif cross_emb is not None:
         cross_emb = cross_emb.astype(cfg.dtype)
 
-    aux_total = jnp.zeros((), jnp.float32)
+    aux_total = _zero_aux(cfg)
     prelude_cache = []
+    block = _run_block
+    if cfg.remat:
+        block = jax.checkpoint(
+            _run_block, static_argnums=(2, 3),
+            policy=jax.checkpoint_policies.nothing_saveable)
     for bp in params.get("prelude", []):
-        x, nc, a = _run_block(x, bp, ("attn", "mlp"), cfg, positions,
-                              cross_emb, None, None)
+        x, nc, a = block(x, bp, cfg.prelude_entry, cfg, positions,
+                         cross_emb, None, None)
         prelude_cache.append(nc)
-        aux_total += a
+        aux_total = _add_aux(aux_total, a)
 
     x, aux, cache = _scan_groups(x, params["groups"], cfg, positions,
                                  cross_emb, None, None, decode=False,
                                  collect_cache=want_cache)
-    aux_total += aux
+    aux_total = _add_aux(aux_total, aux)
     x = apply_norm(x, params["final_norm"], cfg)
     out = {"hidden": x, "aux": aux_total}
     if want_cache:
@@ -307,7 +347,8 @@ def loss_fn(params, cfg: ModelConfig, batch):
     if mask is None:
         mask = jnp.ones_like(tokens)
     mask = mask.at[:, -1].set(0)
-    return lm_loss(params, cfg, out["hidden"], targets, mask) + out["aux"]
+    return lm_loss(params, cfg, out["hidden"], targets, mask) + _aux_loss(
+        out["aux"])
 
 
 # ----------------------------------------------------------------------
@@ -343,7 +384,7 @@ def init_cache(cfg: ModelConfig, batch: int, cache_len: int):
 
     cache = {"groups": tuple(entry_cache(e, True) for e in cfg.layout)}
     if cfg.first_k_dense:
-        cache["prelude"] = [entry_cache(("attn", "mlp"), False)
+        cache["prelude"] = [entry_cache(cfg.prelude_entry, False)
                             for _ in range(cfg.first_k_dense)]
     return cache
 
@@ -355,13 +396,11 @@ def decode_step(params, cfg: ModelConfig, token, cache, cache_index):
     B = token.shape[0]
     positions = jnp.broadcast_to(
         cache_index.astype(jnp.int32), (B, 1))
-    x = params["embed"][token].astype(cfg.dtype)
-    if cfg.norm == "rmsnorm":
-        x = x * jnp.sqrt(cfg.d_model).astype(cfg.dtype)
+    x = _embed(params, cfg, token)
 
     new_prelude = []
     for bp, pc in zip(params.get("prelude", []), cache.get("prelude", [])):
-        x, nc, _ = _run_block(x, bp, ("attn", "mlp"), cfg, positions,
+        x, nc, _ = _run_block(x, bp, cfg.prelude_entry, cfg, positions,
                               None, pc, cache_index)
         new_prelude.append(nc)
 

@@ -11,7 +11,8 @@ Contracts:
     innermost stage is ``step4_filter``, the fold's ``step5_fold``, and
     every ``pallas_call`` carries its kernel's name;
   * **one list** — every ``jax.named_scope`` the program opens is a name
-    of ``telemetry.SCOPES``;
+    of ``telemetry.SCOPES`` (stages) or ``telemetry.LAYER_SCOPES`` (model
+    layers inside them);
   * **spans reach the profiler** — a ``telemetry.span`` recorded under
     ``jax.profiler`` is an event of the trace's host plane, recorder on
     or off, and ``recording()`` turns each backend compile into a
@@ -113,8 +114,9 @@ def test_every_named_scope_is_a_listed_stage():
     for path in SRC.rglob("*.py"):
         used |= set(re.findall(r'named_scope\("([^"]+)"\)',
                                path.read_text()))
-    assert used == set(telemetry.SCOPES)
-    assert len(set(telemetry.SCOPES)) == len(telemetry.SCOPES)
+    assert used == set(telemetry.SCOPES) | set(telemetry.LAYER_SCOPES)
+    names = telemetry.SCOPES + telemetry.LAYER_SCOPES
+    assert len(set(names)) == len(names)
 
 
 def _host_events(trace_dir):

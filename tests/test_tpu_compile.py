@@ -9,6 +9,7 @@ described inside a fixture (never at import), so under several pytest
 workers only the worker given this file loads the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -135,3 +136,40 @@ def test_kernel_compiles_for_v5e(name, one_chip, vgg11_d):
     peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert peak <= V5E_HBM_BYTES, (name, peak)
+
+
+def test_expert_share_compiles_for_v5e(one_chip, monkeypatch):
+    """DeepSeek-V2-Lite's expert share at published widths (hidden 2048,
+    expert width 1408, 8 of 64 experts held, top 6, two shared), forward
+    and backward on one client step of 2 x 2048 tokens: the grouped
+    products (megablox ``gmm``/``tgmm``) take the chip's branch and
+    compile, and no (tokens x experts) capacity buffer appears."""
+    from repro.models import ModelConfig
+    from repro.models.moe import apply_expert_share, make_moe_params
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = ModelConfig(name="v2-share", n_layers=2, d_model=2048, n_heads=16,
+                      n_kv_heads=16, d_ff=10944, vocab_size=12800,
+                      layout=(("mla", "moe"),), first_k_dense=1,
+                      kv_lora_rank=512, qk_nope_head_dim=128,
+                      qk_rope_head_dim=64, v_head_dim=128, n_experts=64,
+                      n_held_experts=8, top_k=6, n_shared_experts=2,
+                      d_expert=1408, capacity_factor=None,
+                      router_aux_coef=0.0, norm_topk_prob=False)
+    shapes = jax.eval_shape(lambda k: make_moe_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    p = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                    sharding=one_chip),
+                     shapes)
+    x = jax.ShapeDtypeStruct((2, 2048, 2048), jnp.bfloat16, sharding=one_chip)
+
+    def loss(p, x):
+        out = apply_expert_share(x, p, cfg)[0]
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(p, x).compile()
+    text = compiled.as_text()
+    kernels = re.findall(r"%(t?gmm)\.\d+ = \S+ custom-call", text)
+    assert sorted(set(kernels)) == ["gmm", "tgmm"] and len(kernels) == 9
+    assert not re.search(r"\[64,\d+,2048\]", text)
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) <= V5E_HBM_BYTES
