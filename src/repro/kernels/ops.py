@@ -12,6 +12,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu import splash_attention as _splash_lib
 
 from . import dequant_fold as _dq
 from . import flash_attention as _fa
@@ -207,6 +208,51 @@ def flash_attention(q, k, v, window=None, softcap=None):
     vt = v.swapaxes(1, 2)
     o = flash_attention_bhsd(qt, kt, vt, window=window, softcap=softcap)
     return o.swapaxes(1, 2)
+
+
+@functools.lru_cache(maxsize=32)
+def _splash(heads: int, seq: int, softcap, interpret: bool):
+    # the largest square tile that divides seq: fewer grid steps, each
+    # of more MXU work, against finer skipping of the masked tiles
+    b = next(b for b in (512, 256, 128) if seq % b == 0)
+    sizes = _splash_lib.BlockSizes(
+        block_q=b, block_kv=b, block_kv_compute=b, block_q_dkv=b,
+        block_kv_dkv=b, block_kv_dkv_compute=b, block_q_dq=b, block_kv_dq=b)
+    mask = _splash_lib.MultiHeadMask(
+        [_splash_lib.CausalMask((seq, seq))] * heads)
+    # the kernel holds its block tables as arrays: make them concrete even
+    # when the first call comes inside a trace, since they are cached
+    with jax.ensure_compile_time_eval():
+        return _splash_lib.make_splash_mha_single_device(
+            mask, block_sizes=sizes, attn_logits_soft_cap=softcap,
+            interpret=interpret)
+
+
+def fused_causal_attention(q, k, v, scale: float, softcap=None):
+    """Causal self-attention through JAX's splash kernel, forward and
+    backward (its own ``custom_vjp``): scores and probabilities live in
+    VMEM tiles only, and masked tiles are skipped.
+
+    Model layout: q (B,S,H,dqk) in any float dtype, k (B,S,K,dqk), v
+    (B,S,K,dv) with H a multiple of K -> (B,S,H,dv) in v's dtype.  q is
+    scaled by ``scale`` in float32 and then cast once to k's dtype;
+    ``softcap`` caps the scaled scores as ``_sdpa`` does.  The batch is
+    folded into the heads: q head ``b H + h`` reads kv head ``b K +
+    h // (H / K)``, the kernel's own grouping.  A sequence is padded at
+    its end to a multiple of 128 and the output cut back: under the
+    causal mask no real query sees a padded key, so that is exact."""
+    B, S, H = q.shape[:3]
+    dv = v.shape[-1]
+    Sp = -(-S // 128) * 128
+    q = (q.astype(jnp.float32) * scale).astype(k.dtype)
+
+    def heads_first(x):
+        x = jnp.pad(x, ((0, 0), (0, Sp - S), (0, 0), (0, 0)))
+        return x.transpose(0, 2, 1, 3).reshape(-1, Sp, x.shape[-1])
+    o = _splash(B * H, Sp, softcap, _interpret())(
+        heads_first(q), heads_first(k), heads_first(v))
+    o = o.reshape(B, H, Sp, dv)[:, :, :S].transpose(0, 2, 1, 3)
+    return o.astype(v.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bs", "bd"))

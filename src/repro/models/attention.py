@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..sharding import shard
+from ..sharding import model_shard_count, shard
 from .config import ModelConfig, Yarn
 from .layers import dense_init, rms_norm
 
@@ -76,6 +76,14 @@ def make_attn_params(key, cfg: ModelConfig, cross: bool = False):
 # ----------------------------------------------------------------------
 # Core softmax attention on explicit q, k, v
 # ----------------------------------------------------------------------
+
+def _q_projection(x, w, path):
+    """``x @ w``; on the fused path accumulated and kept in float32, so
+    that q is rounded once, after rope and the score scale."""
+    if path == "fused":
+        return jnp.matmul(x, w, preferred_element_type=jnp.float32)
+    return x @ w
+
 
 def _sdpa(q, k, v, mask, softcap=None, scale=None):
     """q, k: (B,Sq|Sk,H|K,dh)  v: (B,Sk,K,dv)  mask: broadcastable
@@ -145,6 +153,36 @@ def _blockwise(q, k, v, q_pos, k_pos, window, chunk, softcap=None,
 
 
 # ----------------------------------------------------------------------
+# Which core computes causal self-attention over a whole sequence
+# ----------------------------------------------------------------------
+
+def attention_path(cfg: ModelConfig, seq: int, window=None,
+                   flash: bool = False) -> str:
+    """Which core computes causal self-attention over ``seq`` tokens with
+    no cache, from what the call can observe, never the model: ``fused``
+    (:func:`repro.kernels.ops.fused_causal_attention`) on a TPU with no
+    model-sharded mesh (the kernel does not partition over heads) and no
+    window narrower than the sequence; else ``flash`` (the forward-only
+    kernel, where the mixer offers it and ``use_kernels`` asks for it past
+    ``attn_direct_max``); else ``direct`` (:func:`_sdpa`, S x S scores in
+    HBM) up to ``attn_direct_max`` and ``blockwise`` past it."""
+    if (jax.default_backend() == "tpu" and model_shard_count() == 1
+            and (window is None or window >= seq)):
+        return "fused"
+    if flash and cfg.use_kernels and seq > cfg.attn_direct_max:
+        return "flash"
+    return "direct" if seq <= cfg.attn_direct_max else "blockwise"
+
+
+def _path_event(path: str, q, k, v) -> None:
+    """One ``attention_path`` event on the flight recorder per trace."""
+    from ..fl import telemetry
+    telemetry.event("attention_path", path=path, heads=q.shape[2],
+                    kv_heads=k.shape[2], seq=q.shape[1], dqk=q.shape[-1],
+                    dv=v.shape[-1])
+
+
+# ----------------------------------------------------------------------
 # Self attention block (training / prefill / decode)
 # ----------------------------------------------------------------------
 
@@ -157,7 +195,9 @@ def self_attention(x, p, cfg: ModelConfig, positions, window=None,
     """
     B, S, d = x.shape
     H, K, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    q = (x @ p["wq"]).reshape(B, S, H, dh)
+    path = None if cache is not None else attention_path(cfg, S, window,
+                                                         flash=True)
+    q = _q_projection(x, p["wq"], path).reshape(B, S, H, dh)
     k = (x @ p["wk"]).reshape(B, S, K, dh)
     v = (x @ p["wv"]).reshape(B, S, K, dh)
     q = shard(q, P(None, None, "model", None))
@@ -165,11 +205,16 @@ def self_attention(x, p, cfg: ModelConfig, positions, window=None,
     q = rope(q, positions, cfg.rope_theta)
 
     if cache is None:
-        if cfg.use_kernels and S > cfg.attn_direct_max:
+        _path_event(path, q, k, v)
+        if path == "fused":
+            from ..kernels import ops as kops
+            o = kops.fused_causal_attention(q, k, v, dh ** -0.5,
+                                            cfg.logit_softcap)
+        elif path == "flash":
             from ..kernels import ops as kops
             o = kops.flash_attention(q, k, v, window=window,
                                      softcap=cfg.logit_softcap)
-        elif S <= cfg.attn_direct_max:
+        elif path == "direct":
             mask = _causal_mask(positions, positions, window)
             o = _sdpa(q, k, v, mask, cfg.logit_softcap)
         else:
@@ -234,7 +279,8 @@ def mla_attention(x, p, cfg: ModelConfig, positions):
     H, r = cfg.n_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     with jax.named_scope("mla"):
-        q = (x @ p["wq"]).reshape(B, S, H, dn + dr)
+        path = attention_path(cfg, S)
+        q = _q_projection(x, p["wq"], path).reshape(B, S, H, dn + dr)
         q = shard(q, P(None, None, "model", None))
         ckv = x @ p["wkv_a"]
         c = rms_norm(ckv[..., :r], p["kv_norm"]["scale"])
@@ -253,7 +299,11 @@ def mla_attention(x, p, cfg: ModelConfig, positions):
             [kv[..., :dn], jnp.broadcast_to(k_pe, (B, S, H, dr))], axis=-1)
         v = kv[..., dn:]
         scale = mla_softmax_scale(cfg)
-        if S <= cfg.attn_direct_max:
+        _path_event(path, q, k, v)
+        if path == "fused":
+            from ..kernels import ops as kops
+            o = kops.fused_causal_attention(q, k, v, scale, cfg.logit_softcap)
+        elif path == "direct":
             o = _sdpa(q, k, v, _causal_mask(positions, positions, None),
                       cfg.logit_softcap, scale)
         else:

@@ -8,6 +8,7 @@ v5e chip that is described, not attached; nothing runs.  The topology is
 described inside a fixture (never at import), so under several pytest
 workers only the worker given this file loads the TPU library.
 """
+import math
 import os
 import re
 
@@ -170,6 +171,75 @@ def test_expert_share_compiles_for_v5e(one_chip, monkeypatch):
     kernels = re.findall(r"%(t?gmm)\.\d+ = \S+ custom-call", text)
     assert sorted(set(kernels)) == ["gmm", "tgmm"] and len(kernels) == 9
     assert not re.search(r"\[64,\d+,2048\]", text)
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) <= V5E_HBM_BYTES
+
+
+# the silo cells' decoders at published widths, one layer each:
+# h2o-danube-1.8b (GQA 32/8, head dim 80, window 4096) and
+# DeepSeek-V2-Lite's latent attention (16 heads, qk 192, v 128, YaRN)
+SILO_MIXERS = {
+    "danube_swa": dict(d_model=2560, n_heads=32, n_kv_heads=8, head_dim=80,
+                       d_ff=6912, layout=(("swa", "mlp"),), window=4096),
+    "v2_lite_mla": dict(d_model=2048, n_heads=16, n_kv_heads=16, d_ff=10944,
+                        layout=(("mla", "mlp"),), kv_lora_rank=512,
+                        qk_nope_head_dim=128, qk_rope_head_dim=64,
+                        v_head_dim=128),
+}
+
+
+@pytest.mark.parametrize("mixer", sorted(SILO_MIXERS))
+def test_silo_training_step_has_no_score_matrix(mixer, one_chip,
+                                                monkeypatch):
+    """One client step of a silo cell (2 sequences of 2048 tokens) for
+    two clients: ``grad`` under ``vmap``, the layer under ``remat``.  On
+    the TPU's path the attention is the fused kernel's forward and
+    backward (under the ``mla`` scope in the latent mixer), and no float32
+    (2048, 2048) score or probability buffer of the heads is left in the
+    compiled program."""
+    from repro import models
+    from repro.models import ModelConfig
+    from repro.models.config import Yarn
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    yarn = Yarn(factor=40.0, original_max_position=4096, mscale=0.707,
+                mscale_all_dim=0.707)
+    cfg = ModelConfig(name=mixer, n_layers=1, vocab_size=1024,
+                      yarn=yarn if mixer == "v2_lite_mla" else None,
+                      **SILO_MIXERS[mixer])
+    assert cfg.remat
+    shapes = jax.eval_shape(lambda k: models.init(k, cfg),
+                            jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        shapes)
+    tokens = jax.ShapeDtypeStruct((2, 2, 2048), jnp.int32, sharding=one_chip)
+
+    def client_grads(params, tokens):
+        def grad(tok):
+            return jax.grad(models.loss_fn)(params, cfg, {"tokens": tok})
+        return jax.vmap(grad)(tokens)
+    compiled = jax.jit(client_grads).lower(params, tokens).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # (..., 2048, 2048) float32 buffers: the scores or probabilities of
+    # every head, else activations at a width of 2048 (leading dims the
+    # clients and the batch only)
+    per_head = [math.prod(int(d) for d in lead.split(",") if d)
+                for lead in re.findall(r"f32\[([0-9,]*)2048,2048\]", text)]
+    assert max(per_head, default=0) < cfg.n_heads
+    # forward, remat forward, dq and dkv, each under the layer's scope
+    # where the mixer opens one (``mla``, which ``mla_ms`` reads)
+    calls = {}
+    for instr in text.split("\n  %"):
+        m = re.match(r"(splash_mha_[a-z]+)_[\w.]+ = ", instr)
+        if m:
+            calls.setdefault(m.group(1), []).append(
+                re.search(r'op_name="([^"]+)"', instr).group(1))
+    assert sorted(calls) == ["splash_mha_dkv", "splash_mha_dq",
+                             "splash_mha_fwd"]
+    if mixer == "v2_lite_mla":
+        assert all("/mla/" in n for names in calls.values() for n in names)
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) <= V5E_HBM_BYTES
