@@ -51,6 +51,7 @@ PAPER_LR = 0.05
 PER_CLIENT, N_TEST = 64, 256     # synthetic CIFAR-like images
 DECODER_N, DECODER_F = 4, 1
 DECODER_LR = 3e-2
+DECODER_SEQ = 32
 # Kernel vs XLA Step 4+5 on one identical (23, 28.1M) input pair, as
 # |ddelta| / |delta|.  On a v5e the kernel reads 1.05e-7, a reversed
 # XLA fold 1.16e-7, and a kernel that drops one kept client 0.236: the
@@ -315,9 +316,13 @@ def decoder_run(model, cfg, label: str, mesh=None):
 
 
 def decoder_model():
-    from benchmarks.model_fl_bench import FULL_MODEL, SEQ
     from repro.fl import zoo_model
-    model = zoo_model(FULL_MODEL, seq_len=SEQ)
+    from repro.models import ModelConfig
+    # 13 x (640, 8H/4KV, 2560ff) + 32k vocab = 100,369,280 params
+    full = ModelConfig(name="fl-llm-100m", n_layers=13, d_model=640,
+                       n_heads=8, n_kv_heads=4, d_ff=2560,
+                       vocab_size=32_000, attn_direct_max=DECODER_SEQ)
+    model = zoo_model(full, seq_len=DECODER_SEQ)
     log(f"[decoder] {model.name}: {model.param_count():,} params, "
         f"N={DECODER_N}, f={DECODER_F} sign_flip, streaming, "
         f"client_chunk=1, {ROUNDS} rounds")

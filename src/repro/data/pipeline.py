@@ -5,15 +5,10 @@ can be vmapped; plus the once-before-training enclave sample draw (Step 1).
 from __future__ import annotations
 
 import dataclasses
-import functools
-from typing import List, Optional, Tuple
+from typing import List, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-
-from ..sharding import (client_put, data_shard_count, get_mesh,
-                        put_clients_by_shard)
 
 
 @dataclasses.dataclass
@@ -58,54 +53,6 @@ class FederatedData:
             return xs[idx], ys[idx]
         return jax.vmap(take)(keys, self.x, self.y)
 
-    def segment_minibatches(self, keys, batch_size: int):
-        """Minibatch stacks for one scan segment of the round engine.
-
-        ``keys``: (T, 2) — one ``kb`` subkey per round, derived by the
-        engine with the same chain the per-round path uses, so row t is
-        bit-identical to ``minibatch(keys[t], batch_size)``.  Returns
-        ``(T, N, m, ...), (T, N, m)`` with the client axis (dim 1)
-        placed on the mesh's data axes when one is active
-        (sharding/api.client_put) — batch data for a sharded segment
-        lives distributed from the start instead of being scattered by
-        the first round's constraint.
-
-        With a mesh that splits the client axis more than one way the
-        stack is built **shard by shard** (DESIGN.md §9): each client
-        shard's rows are sampled independently — from the same
-        per-client subkeys the one-shot build derives, so the bits are
-        identical — placed directly on that shard's device, and the
-        global array assembled from the per-device pieces
-        (sharding/api.put_clients_by_shard).  No single host buffer
-        ever holds the full ``(T, N, m, ...)`` stack, which is what
-        lets a multi-pod federation stage batch stacks whose union
-        exceeds one host's memory.
-        """
-        mesh = get_mesh()
-        N = self.n_clients
-        if mesh is not None and data_shard_count(mesh) > 1 \
-                and N % data_shard_count(mesh) == 0:
-            T = int(keys.shape[0])
-            ckeys = _client_round_keys(keys, N)
-            built = {}      # one sample per client range; replicas reuse it
-
-            def build(lo, hi):
-                if (lo, hi) not in built:
-                    built[(lo, hi)] = _take_minibatches(
-                        ckeys[:, lo:hi], self.x[lo:hi], self.y[lo:hi],
-                        batch_size)
-                return built[(lo, hi)]
-
-            xshape = (T, N, batch_size) + tuple(self.x.shape[2:])
-            yshape = (T, N, batch_size)
-            xb = put_clients_by_shard(lambda lo, hi: build(lo, hi)[0],
-                                      xshape, axis=1, mesh=mesh)
-            yb = put_clients_by_shard(lambda lo, hi: build(lo, hi)[1],
-                                      yshape, axis=1, mesh=mesh)
-            return xb, yb
-        xb, yb = _stacked_minibatches(keys, self.x, self.y, batch_size)
-        return client_put(xb, axis=1), client_put(yb, axis=1)
-
     def enclave_samples(self, key, frac: float):
         """Step 1: uniform sample M_j^0 (size s = frac * n_j) per client."""
         s = max(1, int(self.per_client * frac))
@@ -115,42 +62,6 @@ class FederatedData:
             idx = jax.random.choice(k, self.per_client, (s,), replace=False)
             return xs[idx], ys[idx]
         return jax.vmap(take)(keys, self.x, self.y)
-
-
-@functools.partial(jax.jit, static_argnames=("n",))
-def _client_round_keys(keys, n: int):
-    """(T, 2) round keys -> (T, n, 2) per-client subkeys: exactly the
-    ``jax.random.split(k, n)`` every round of ``minibatch`` performs,
-    precomputed so the shard-by-shard build can slice client ranges out
-    of the *same* key matrix the one-shot build consumes."""
-    return jax.vmap(lambda k: jax.random.split(k, n))(keys)
-
-
-@functools.partial(jax.jit, static_argnames=("batch_size",))
-def _take_minibatches(ckeys, x, y, batch_size: int):
-    """(T, C, 2) per-client subkeys + (C, per_client, ...) client data ->
-    (T, C, m, ...), (T, C, m) stacks.  Per-(round, client) draws are
-    independent (one randint + one gather each), so building a client
-    *slice* is bit-identical to slicing the full build — the invariant
-    the shard-by-shard segment staging rests on."""
-    per_client = y.shape[1]
-
-    def take(kc, xs, ys):
-        idx = jax.random.randint(kc, (batch_size,), 0, per_client)
-        return xs[idx], ys[idx]
-
-    return jax.vmap(lambda ks: jax.vmap(take)(ks, x, y))(ckeys)
-
-
-@functools.partial(jax.jit, static_argnames=("batch_size",))
-def _stacked_minibatches(keys, x, y, batch_size: int):
-    """(T, 2) round keys -> (T, N, m, ...), (T, N, m) minibatch stacks.
-
-    Row t is bit-identical to ``FederatedData.minibatch(keys[t], m)``
-    (same key split, same randint draw); jitted so serving a segment is
-    one cached dispatch rather than a fresh eager-vmap trace."""
-    return _take_minibatches(_client_round_keys(keys, y.shape[0]),
-                             x, y, batch_size)
 
 
 def batch_iterator(key, x, y, batch_size: int):

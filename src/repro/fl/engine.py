@@ -5,13 +5,14 @@ client local training over the *entire* federation, so (i) every round
 paid a Python dispatch + host sync and (ii) peak memory was
 O(N x model) — N capped at what one device holds.  The engine removes
 both limits while keeping the round math — Algorithm 1 Steps 2-5 —
-byte-identical to the per-round path:
+byte-identical to a per-round loop over the same body:
 
   * **Scan segmentation** — ``eval_every`` rounds compile into a single
-    donated ``jax.lax.scan``: one dispatch and one host sync per eval
-    segment.  Per-round RNG subkeys and learning rates are precomputed
-    host-side with exactly the legacy ``key, sub = split(key)`` chain,
-    so the scan consumes the same key sequence the Python loop would.
+    donated ``jax.lax.scan``, and a whole run into one outer scan over
+    those segments with the eval inside: one dispatch and one host
+    sync.  Per-round RNG subkeys and learning rates are precomputed
+    with exactly the ``key, sub = split(key)`` chain a Python loop
+    would run, so the scan consumes the same key sequence.
   * **Client chunking** — local training and guiding updates run in
     ``client_chunk``-sized blocks via ``jax.lax.map``
     (fl/chunking.chunked_vmap), so a 1000-client federation peaks at
@@ -25,10 +26,10 @@ byte-identical to the per-round path:
     semantics with launch/train.py's one-client-per-mesh-coordinate
     shard_map path.
 
-``make_round_body`` is the single round-step definition: the legacy
-per-round path (fl/simulator.py, the benchmark baseline) jits it
-directly; the engine scans it.  Equivalence is enforced by
-tests/test_engine.py.
+``make_round_body`` is the single round-step definition: the engine
+scans it, and the tests' per-round reference loop
+(``tests/conftest.py``) jits it round by round.  Equivalence is
+enforced by tests/test_engine.py.
 """
 from __future__ import annotations
 
@@ -113,8 +114,8 @@ def make_scenario(cfg, fed=None, byz_mask=None, cohort=None):
 
 # Compiles are counted, not inferred: each outer jitted program calls
 # its Python body exactly once per cache miss (trace), so a counter
-# bumped inside the body is a compile counter.  benchmarks/sweep_bench
-# snapshots it to enforce "one compile per structural group"; the
+# bumped inside the body is a compile counter.  fl/sweep.py records it
+# per structural group ("one compile per group"); the
 # no-recompile-on-sigma-change regression test reads it too.
 TRACE_COUNTS = {"segment": 0, "training": 0, "eval": 0}
 
@@ -242,17 +243,14 @@ def dense_rows_reason(cfg, *, streaming: bool, lossy: bool,
 
 
 def make_round_body(model, fed, cfg, *, client_chunk: Optional[int] = None):
-    """Build ``body(params, sub, lr, batch) -> (new_params, logs)``.
+    """Build ``body(carry, sub, lr, scen) -> (new_carry, logs)``.
 
-    ``sub`` is the round's RNG key, ``lr`` its learning rate, ``batch``
-    an optional precomputed ``(xb, yb)`` minibatch stack (shape
-    (N, E*m, ...)) — ``None`` samples inside the traced body with the
-    same ``kb`` subkey the precomputed path derives, so the two modes
-    are bit-identical.  ``scen`` carries the run's traced operands
-    (:func:`make_scenario`: attack sigma/scale, the Byzantine mask);
-    ``None`` closes over the federation's own values — same bits, but
-    baked into the trace (the seed per-round path; every engine path
-    threads ``scen`` through as a jit argument instead).
+    ``sub`` is the round's RNG key and ``lr`` its learning rate; each
+    client's minibatches are sampled inside the traced body from
+    ``sub``'s ``kb`` subkey.  ``scen`` carries the run's traced
+    operands (:func:`make_scenario`: attack sigma/scale, the Byzantine
+    mask), threaded through as a jit argument rather than baked into
+    the trace.
 
     With ``cfg.streaming`` and an associative aggregator, Steps 2-5 run
     through the streaming subsystem (fl/streaming.py): client updates
@@ -306,7 +304,6 @@ def make_round_body(model, fed, cfg, *, client_chunk: Optional[int] = None):
     # discount is one static factor riding the fold's valid channel
     discount_w = (float(getattr(cfg, "staleness_discount", 1.0))
                   ** fcfg.delay) if async_mode else 1.0
-    default_scen = make_scenario(cfg, fed)
     stream_entry, streaming_fallback = None, None
     if getattr(cfg, "streaming", False):
         stream_entry = get_streaming(cfg.aggregator)
@@ -351,7 +348,7 @@ def make_round_body(model, fed, cfg, *, client_chunk: Optional[int] = None):
             theta, _ = jax.lax.scan(step, params, (xs, ys))
             return jax.tree.map(lambda a, b: a - b, params, theta)
 
-    def body(carry, sub, lr, batch=None, scen=None):
+    def body(carry, sub, lr, scen):
         astate = None
         if lossy:
             params, resid = carry       # resid: (N, d) f32 EF residuals
@@ -362,13 +359,8 @@ def make_round_body(model, fed, cfg, *, client_chunk: Optional[int] = None):
             resid = None
         else:
             params, resid = carry, None     # bare-params carry, as ever
-        if scen is None:
-            scen = default_scen
         kb, ka, kr, ks = jax.random.split(sub, 4)
-        if batch is None:
-            xb, yb = fed.data.minibatch(kb, E * m)
-        else:
-            xb, yb = batch
+        xb, yb = fed.data.minibatch(kb, E * m)
         xb = xb.reshape((cfg.n_clients, E, m) + xb.shape[2:])
         yb = yb.reshape((cfg.n_clients, E, m))
         # Step 2 preamble: server samples the participating subset S^i
@@ -742,11 +734,6 @@ def make_round_body(model, fed, cfg, *, client_chunk: Optional[int] = None):
     return body
 
 
-# each round's batch subkey, exactly as the body derives it:
-# kb = split(sub, 4)[0] (jitted once; eager vmap would retrace per call)
-_batch_keys = jax.jit(jax.vmap(lambda s: jax.random.split(s, 4)[0]))
-
-
 # ----------------------------------------------------------------------
 # RoundEngine
 # ----------------------------------------------------------------------
@@ -756,9 +743,9 @@ class RoundEngine:
     the whole training run.
 
     ``run_segment(params, key, lrs)`` executes ``len(lrs)`` rounds in a
-    single dispatch, advancing the caller's RNG chain exactly as the
-    legacy per-round loop would (``key, sub = split(key)`` per round),
-    and returns ``(params, key, last_logs)`` where ``last_logs`` is the
+    single dispatch, advancing the caller's RNG chain exactly as a
+    per-round loop would (``key, sub = split(key)`` per round), and
+    returns ``(params, key, last_logs)`` where ``last_logs`` is the
     final round's log dict — the one the eval point reads.
 
     ``run_training(params, key, lrs)`` goes one level further: the whole
@@ -771,42 +758,21 @@ class RoundEngine:
     a stacked scenario axis — a whole structural group of runs in one
     compile and one dispatch (fl/sweep.py, DESIGN.md §8).
 
-    ``batch_mode``:
-      * ``"inline"``  — minibatches are sampled inside the traced body
-        (memory-light; the default off-mesh);
-      * ``"segment"`` — the data pipeline serves a per-segment
-        minibatch stack (data/pipeline.segment_minibatches) placed with
-        client-axis NamedShardings (the default when a mesh is active,
-        so batch data lives distributed from the start).
-    Both derive batches from the same ``kb`` subkeys — bit-identical.
-    ``run_segment`` honors the mode; ``run_training`` always samples
-    inline (a whole run's batch stacks would scale the batch working
-    set by the segment count).
-
-    ``donate``: tri-state scan-carry donation knob.  ``None`` resolves
-    to ``cfg.donate``, and a ``None`` there means *auto* — donate
-    wherever the backend supports it (XLA:CPU does not, so auto skips
-    the warning-spamming request there).  ``True``/``False`` force the
-    request on or off regardless of backend, which is what lets
-    benchmarks/dispatch_bench measure the donation working-set delta.
+    Every program samples each round's minibatches inside the traced
+    body, and donates its carry wherever the backend supports it
+    (``self.donate``; XLA:CPU does not, so the request is skipped
+    there).
     """
 
     @telemetry.span("fl.engine")
     def __init__(self, model, fed, cfg, *, eval_every: Optional[int] = None,
-                 client_chunk: Optional[int] = None,
-                 batch_mode: Optional[str] = None, mesh=None,
-                 donate: Optional[bool] = None):
+                 client_chunk: Optional[int] = None, mesh=None):
         self.model, self.fed, self.cfg = model, fed, cfg
         self.eval_every = eval_every if eval_every is not None \
             else cfg.eval_every
         self.client_chunk = client_chunk if client_chunk is not None \
             else getattr(cfg, "client_chunk", None)
         self.mesh = mesh if mesh is not None else get_mesh()
-        if batch_mode is None:
-            batch_mode = "segment" if self.mesh is not None else "inline"
-        if batch_mode not in ("inline", "segment"):
-            raise ValueError(f"unknown batch_mode {batch_mode!r}")
-        self.batch_mode = batch_mode
         # tensor parallelism: >1 iff the mesh carries a non-trivial
         # ``model`` axis.  The knob-compatibility check needs the flat
         # model dim, which only exists once params are seen — deferred
@@ -840,16 +806,11 @@ class RoundEngine:
         self.telemetry = bool(getattr(cfg, "telemetry", False))
         self._tel_fn = telemetry.make_round_telemetry_fn(cfg) \
             if self.telemetry else None
-        if donate is None:
-            donate = getattr(cfg, "donate", None)
-        if donate is None:                   # auto: backend support only
-            donate = jax.default_backend() != "cpu"
-        self.donate = bool(donate)
+        self.donate = jax.default_backend() != "cpu"
         self.default_scenario = make_scenario(cfg, fed)
-        jit_kwargs = {"static_argnums": (3,)}
         donate_kw = {"donate_argnums": (0,)} if self.donate else {}
-        self._segment = jax.jit(_counted("segment", self._segment_fn),
-                                **jit_kwargs, **donate_kw)
+        self._segment = jax.jit(_counted("segment", self._scan_rounds),
+                                **donate_kw)
         self._training = jax.jit(_counted("training", self._training_fn),
                                  **donate_kw)
         # the sweep twins: one extra leading scenario axis on every
@@ -860,8 +821,7 @@ class RoundEngine:
         self._training_sweep = jax.jit(
             jax.vmap(_counted("training", self._training_fn)), **donate_kw)
         self._segment_sweep = jax.jit(
-            jax.vmap(_counted("segment", self._segment_sweep_fn)),
-            **donate_kw)
+            jax.vmap(_counted("segment", self._scan_rounds)), **donate_kw)
         self._eval_fn = make_eval_fn(model, fed, cfg)
         self._eval_jit = jax.jit(_counted("eval", self._eval_fn))
         self._eval_sweep = jax.jit(jax.vmap(_counted("eval", self._eval_fn)))
@@ -941,7 +901,7 @@ class RoundEngine:
             return (params, carry[1])
         return params
 
-    def _scan_rounds(self, params, subs, lrs, with_batches, batches, scen):
+    def _scan_rounds(self, params, subs, lrs, scen):
         """One segment: scan ``len(lrs)`` round bodies, return the final
         round's logs (the only logs an eval point reads) plus the
         per-round telemetry block (``{}`` with telemetry off — the extra
@@ -949,15 +909,11 @@ class RoundEngine:
         unchanged).  ``scen`` is scan-invariant — the same operand every
         round reads."""
         def step(p, xs):
-            if with_batches:
-                sub, lr, batch = xs
-            else:
-                (sub, lr), batch = xs, None
-            p, logs = self._body(p, sub, lr, batch, scen)
+            sub, lr = xs
+            p, logs = self._body(p, sub, lr, scen)
             tel = self._tel_fn(logs) if self._tel_fn is not None else {}
             return p, (logs, tel)
-        xs = (subs, lrs, batches) if with_batches else (subs, lrs)
-        params, (logs, tel) = jax.lax.scan(step, params, xs)
+        params, (logs, tel) = jax.lax.scan(step, params, (subs, lrs))
         # only the final round's logs leave the device: that is what the
         # eval point reads, and slicing inside the compiled segment keeps
         # the host side to one dispatch (T eager slices would dwarf the
@@ -966,36 +922,23 @@ class RoundEngine:
         # so its (T,)-stacked leaves ride the same dispatch.
         return params, jax.tree.map(lambda x: x[-1], logs), tel
 
-    def _segment_fn(self, params, subs, lrs, with_batches, batches, scen):
-        return self._scan_rounds(params, subs, lrs, with_batches, batches,
-                                 scen)
-
-    def _segment_sweep_fn(self, params, subs, lrs, scen):
-        """The vmappable segment program (no precomputed batch stacks —
-        sweeps always sample in-body, like ``run_training``)."""
-        return self._scan_rounds(params, subs, lrs, False, None, scen)
-
     def _training_fn(self, params, subs, lrs, scen):
         """The one-dispatch program: outer scan over (S, T)-shaped
         segment stacks; each step runs the segment scan then the device
         eval tail, so the stacked ys are the (num_evals, k) metric
         buffer — plus the (S, T)-stacked per-round telemetry block when
         telemetry is on — and nothing but the final carry + buffers
-        leaves XLA.  Minibatches are always sampled inside the traced
-        body (bit-identical to the per-segment batch stacks — same
-        ``kb`` subkeys): a whole-run (S, T, N, m, ...) stack would scale
-        the batch working set by S, the opposite of the constant-memory
-        story the engine exists for."""
+        leaves XLA."""
         def seg(p, xs):
             sub, lr = xs
-            p, logs, tel = self._scan_rounds(p, sub, lr, False, None, scen)
+            p, logs, tel = self._scan_rounds(p, sub, lr, scen)
             return p, (self._eval_fn(self.carry_params(p), logs), tel)
         return jax.lax.scan(seg, params, (subs, lrs))
 
     @staticmethod
     @functools.partial(jax.jit, static_argnums=(1,))
     def _segment_keys(key, n_rounds: int):
-        """The legacy loop's exact per-round subkey chain (``key, sub =
+        """A per-round loop's exact subkey chain (``key, sub =
         split(key)`` n times), staged as one scan so precomputing a
         segment's keys costs one dispatch, not n."""
         def step(k, _):
@@ -1013,9 +956,8 @@ class RoundEngine:
         Under lossy compression the params slot is the ``(params,
         resid)`` carry — bare params are accepted (zero residual) and
         the advanced *carry* is returned, so chained ``run_segment``
-        calls (the host-eval loop) keep the error feedback flowing;
-        ``carry_params`` unwraps.  Lossless codecs: params in, params
-        out, exactly as before."""
+        calls keep the error feedback flowing; ``carry_params``
+        unwraps.  Lossless codecs: params in, params out."""
         if scen is None:
             scen = self.default_scenario
         lrs = jnp.asarray(lrs, jnp.float32)
@@ -1023,15 +965,7 @@ class RoundEngine:
         key, subs = self._segment_keys(key, n)
         carry = self._prepare_carry(params)
         with use_mesh(self.mesh):
-            if self.batch_mode == "segment":
-                kbs = _batch_keys(subs)
-                batches = self.fed.data.segment_minibatches(
-                    kbs, self.cfg.local_steps * self.cfg.batch_size)
-                carry, logs, _ = self._segment(carry, subs, lrs, True,
-                                               batches, scen)
-            else:
-                carry, logs, _ = self._segment(carry, subs, lrs, False, None,
-                                               scen)
+            carry, logs, _ = self._segment(carry, subs, lrs, scen)
         return carry, key, logs
 
     def _training_args(self, params, key, lrs, scen):
@@ -1072,10 +1006,7 @@ class RoundEngine:
         the one sync).  The RNG chain, segmentation, and eval points are
         exactly ``run_segment`` in a loop: a non-divisible ``rounds``
         leaves a shorter final segment, which runs as one extra dispatch
-        with its eval row concatenated on device.  Minibatches are
-        sampled inside the scan regardless of ``batch_mode`` — the
-        modes are bit-identical, and staging a whole run's batch stacks
-        would multiply the batch working set by the segment count.
+        with its eval row concatenated on device.
 
         Returns ``(params, advanced key, metrics, eval_rounds)`` where
         ``metrics`` is a dict of device arrays with leading dim = number
@@ -1102,7 +1033,7 @@ class RoundEngine:
                 # the carry — residual included — flows into the tail
                 # segment: error feedback does not reset at eval points
                 carry, logs, tel_tail = self._segment(
-                    carry, subs[S * T:], lrs[S * T:], False, None, scen)
+                    carry, subs[S * T:], lrs[S * T:], scen)
                 row = jax.tree.map(
                     lambda x: jnp.asarray(x)[None],
                     self._eval_jit(self.carry_params(carry), logs))

@@ -9,7 +9,7 @@ never compile into the round engine's scan.  Every metric here is a
     the same function runs eagerly, under ``jax.jit``, or in the scan
     tail of :class:`~repro.fl.engine.RoundEngine`;
   * counts are integer sums (exact under any reduction association —
-    what makes the in-scan eval bitwise-equal to the host-loop eval)
+    what makes the in-scan eval bitwise-equal to an eval on the host)
     with a single fp32 division at the end;
   * results are **device scalars** — nothing here syncs the host.
 
@@ -243,10 +243,10 @@ def make_eval_fn(model, fed, cfg):
     """Build ``eval_fn(params, logs) -> {metric: device array}`` — the
     one eval definition every execution mode shares.
 
-    The host-loop path jits it and calls it once per segment; the
+    ``RoundEngine.eval_metrics`` jits it for one eval point; the
     one-dispatch path traces the *same function* into the scan tail of
-    ``RoundEngine.run_training``, which is why the two paths agree
-    bitwise (integer-count metrics are association-free).  The metric
+    ``RoundEngine.run_training``, which is why the two agree bitwise
+    (integer-count metrics are association-free).  The metric
     set is static per config: main-task + backdoor accuracy appear under
     a backdoor attack, detection TPR/FPR and the C1·C2 criterion logs
     whenever the aggregator emits a keep-mask, and a model with a

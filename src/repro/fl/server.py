@@ -15,7 +15,7 @@ Every aggregation path in the repo routes through this module
     ``fn(U, ctx) -> (delta, logs)`` where ``U`` is the stacked (N, D)
     update matrix and ``ctx`` is an :class:`AggregationContext`.  This
     replaces the per-call-site if/elif dispatch the seed carried in
-    fl/simulator.py and benchmarks/.
+    fl/simulator.py.
 
 The DiverseFL rule itself imports its mask/statistics/aggregation math
 from core/diversefl.py (one source of truth) and can route Step 4+5

@@ -7,17 +7,17 @@ updates (gaussian / sign flip / same value / x5 scaling), then the round
 is handed to the SecureServer (fl/server.py) and the aggregator
 registry.
 
-Training runs through the :class:`~repro.fl.engine.RoundEngine`: each
-``eval_every`` segment of rounds compiles into one donated
-``jax.lax.scan`` (one dispatch + one host sync per segment), client
-local training and guiding updates are bounded to ``client_chunk``-sized
-blocks, and the client axis is sharded over the mesh's data axes when
-one is active.  ``FLConfig(streaming=True)`` additionally folds the
-aggregation into the chunked sweep (fl/streaming.py): associative rules
-never materialize the (N, D) update/guide matrices, bit-identically to
-the dense path (DESIGN.md §6).  ``use_engine=False`` keeps the seed
-per-round jitted loop — the benchmark baseline and the bit-for-bit
-reference the engine is tested against (tests/test_engine.py).
+Training runs through the :class:`~repro.fl.engine.RoundEngine`: the
+whole run compiles into one donated outer ``jax.lax.scan`` over
+``eval_every`` segments of rounds with the eval inside (one dispatch,
+one host sync), client local training and guiding updates are bounded
+to ``client_chunk``-sized blocks, and the client axis is sharded over
+the mesh's data axes when one is active.  ``FLConfig(streaming=True)``
+additionally folds the aggregation into the chunked sweep
+(fl/streaming.py): associative rules never materialize the (N, D)
+update/guide matrices, bit-identically to the dense path (DESIGN.md
+§6).  The per-round reference loop the engine is tested against lives
+in the tests (``tests/conftest.py``).
 """
 from __future__ import annotations
 
@@ -33,9 +33,9 @@ from ..core.attacks import AttackConfig, make_byzantine_mask
 from ..data.pipeline import FederatedData
 from . import telemetry
 from .compression import available_codecs, get_codec
-from .engine import RoundEngine, make_round_body, make_scenario
-from .faults import FaultConfig, init_async_state
-from .metrics import BackdoorEval, comm_stats, make_backdoor_eval, make_eval_fn
+from .engine import RoundEngine, make_scenario
+from .faults import FaultConfig
+from .metrics import BackdoorEval, comm_stats, make_backdoor_eval
 from .server import KERNEL_AGG_RULES, SecureServer, available_aggregators
 from .small_models import SmallModel
 from .streaming import fallback_reason, get_streaming
@@ -77,10 +77,6 @@ class FLConfig:
     #                                      folds tree-merged across pods —
     #                                      pods=1 IS the single-tier fold,
     #                                      bitwise (DESIGN.md §9)
-    donate: Optional[bool] = None        # scan-carry buffer donation: None =
-    #                                      auto (on wherever the backend
-    #                                      supports it, i.e. off on CPU),
-    #                                      True/False force it
     compression: str = "f32"             # client→server update codec
     #                                      (fl/compression.py): "f32" is the
     #                                      lossless wire format (bitwise the
@@ -387,30 +383,12 @@ class Federation:
 
 # ----------------------------------------------------------------------
 
-def _build_round_step(model: SmallModel, fed: Federation, cfg: FLConfig):
-    """The seed per-round path: one jitted dispatch per round.
-
-    Kept as the benchmark baseline (benchmarks/engine_bench.py) and as
-    the reference the scan engine must reproduce bit-for-bit; it jits
-    the very same round body the engine scans."""
-    body = make_round_body(model, fed, cfg, client_chunk=cfg.client_chunk)
-    if cfg.async_rounds:
-        # the async body reads the cohort chain off the scenario; baking
-        # it as a jit constant is fine here — this path re-jits per
-        # config anyway (the engine threads it as a traced operand)
-        scen = make_scenario(cfg, fed)
-        return jax.jit(lambda carry, key, lr: body(carry, key, lr,
-                                                   scen=scen))
-    return jax.jit(lambda params, key, lr: body(params, key, lr))
-
-
 def host_sync(tree):
     """The simulator's single device→host materialization point.
 
     Every value ``run_federated_training`` moves off the device flows
-    through here — the legacy host-eval loop once per eval segment, the
-    one-dispatch path exactly once per training run.  Keeping one choke
-    point makes the sync count *measurable*: benchmarks/dispatch_bench
+    through here, exactly once per training run.  Keeping one choke
+    point makes the sync count *measurable*: tests/test_dispatch_eval.py
     wraps this function with a counter and runs training under
     ``jax.transfer_guard_device_to_host("disallow_explicit")``, so on
     accelerator backends a host read that bypasses it raises instead of
@@ -420,7 +398,7 @@ def host_sync(tree):
     When the flight recorder is on, each sync emits a ``sync`` event
     carrying the bytes moved (sum of leaf ``nbytes``) and the fetch wall
     time — the one-sync contract becomes *visible* in a recorded run,
-    not just counted in the dispatch bench."""
+    not just counted in a test."""
     rec = telemetry.get_recorder()
     if not rec.enabled:
         with jax.transfer_guard_device_to_host("allow"):
@@ -494,7 +472,7 @@ def _record_eval(history, i, metrics, log_every):
 def _lr_vector(lr_schedule: Callable, rounds: int) -> jnp.ndarray:
     """Evaluate the schedule for rounds 1..R as one device (R,) vector.
 
-    The legacy loop called ``float(lr_schedule(i))`` per round — R tiny
+    The seed loop called ``float(lr_schedule(i))`` per round — R tiny
     device→host transfers before training even dispatched (and a
     transfer-guard violation on accelerator backends).  One vmap keeps
     the values on device, bit-identical per element for the repo's
@@ -512,35 +490,24 @@ def _lr_vector(lr_schedule: Callable, rounds: int) -> jnp.ndarray:
 
 def run_federated_training(model: SmallModel, fed: Federation, cfg: FLConfig,
                            lr_schedule: Callable, log_every: int = 0,
-                           use_engine: bool = True, host_eval: bool = False,
                            engine: Optional[RoundEngine] = None) -> Dict:
     """Run ``cfg.rounds`` federated rounds; returns the metric history.
 
-    Engine mode (the default) is **one-dispatch**: the whole run
-    compiles into a single outer scan over eval segments with the eval
-    metrics accumulated on device (`RoundEngine.run_training`), and the
-    host syncs exactly once at the end.  ``host_eval=True`` keeps the
-    legacy per-segment loop — one dispatch and one host sync per eval
-    segment, the bitwise reference the in-scan eval is tested against.
-    ``use_engine=False`` keeps the seed per-round jitted loop (benchmark
-    baseline).  All three paths evaluate through the same jitted metric
-    functions (fl/metrics.make_eval_fn), so their histories agree
-    bit-for-bit.  ``engine`` reuses a prebuilt (already-compiled)
-    ``RoundEngine`` instead of constructing one per call — what lets
-    benchmarks time repeat runs without retracing.
+    The run is **one-dispatch**: it compiles into a single outer scan
+    over eval segments with the eval metrics accumulated on device
+    (`RoundEngine.run_training`), and the host syncs exactly once at the
+    end.  ``engine`` reuses a prebuilt (already-compiled)
+    ``RoundEngine`` instead of constructing one per call.
 
-    ``log_every`` prints eval lines as they reach the host: live per
-    segment on the ``host_eval=True`` and seed-loop paths, but on the
-    one-dispatch default everything is on device until the single final
-    sync, so the lines appear together at the end — use
-    ``host_eval=True`` when watching a long run interactively.
+    ``log_every`` prints eval lines as they reach the host: everything
+    is on device until the single final sync, so the lines appear
+    together at the end.
     """
     key = jax.random.PRNGKey(cfg.seed)
     params = model.init(jax.random.PRNGKey(cfg.seed + 1))
     history = {"round": [], "acc": [], "mask_tpr": [], "mask_fpr": [],
                "c1c2": []}
-
-    if use_engine and engine is None:
+    if engine is None:
         engine = RoundEngine(model, fed, cfg)
 
     lrs_all = _lr_vector(lr_schedule, cfg.rounds)
@@ -548,89 +515,38 @@ def run_federated_training(model: SmallModel, fed: Federation, cfg: FLConfig,
     # derived from *this call's* cfg/fed, not the engine's, so reusing a
     # prebuilt engine with a magnitude-only cfg change is a cache hit,
     # never a stale constant (tests/test_sweep.py pins the no-retrace)
-    scen = make_scenario(cfg, fed) if use_engine else None
+    scen = make_scenario(cfg, fed)
 
     # d from aval metadata (p.size is the GLOBAL size of a sharded
     # array — no device gather, no host sync); the wire stats price the
     # per-shard encoding when the engine runs tensor-sharded
     d_model = sum(p.size for p in jax.tree.leaves(params))
-    cstats = comm_stats(
-        cfg, d_model,
-        model_shards=engine.model_shards if engine is not None else 1)
-    run_span = telemetry.span(
-        "run_training", n_clients=cfg.n_clients, rounds=cfg.rounds,
-        aggregator=cfg.aggregator, attack=cfg.attack.kind, d=int(d_model),
-        chunk=cfg.client_chunk, pods=cfg.pods, codec=cfg.compression,
-        streaming=bool(getattr(cfg, "streaming", False)),
-        mode=("one-dispatch" if use_engine and not host_eval
-              else "host-eval" if use_engine else "per-round"))
-
-    if use_engine and not host_eval:
-        with run_span:
-            params, key, metrics, eval_rounds = engine.run_training(
-                params, key, lrs_all, scen)
-            if metrics is not None:                    # rounds >= 1
-                host = host_sync(metrics)              # THE host sync
-                # the reserved telemetry block rides the same sync and is
-                # drained here — it never enters the history
-                drain_round_telemetry(
-                    fed.server, host.pop("_tel", None),
-                    uplink_bytes=cstats["uplink_bytes_per_round"])
-                for s, i in enumerate(eval_rounds):
-                    _record_eval(history, i,
-                                 {k: v[s] for k, v in host.items()},
-                                 log_every)
-    elif use_engine:
-        # run_segment carries (params, resid) under lossy compression —
-        # chaining the returned carry is what keeps error feedback
-        # flowing across eval segments; eval reads the params inside
-        with run_span:
-            carry = engine.init_carry(params)
-            i = 0
-            while i < cfg.rounds:
-                n = min(engine.eval_every, cfg.rounds - i)
-                carry, key, logs = engine.run_segment(carry, key,
-                                                      lrs_all[i:i + n], scen)
-                i += n
-                _record_eval(
-                    history, i,
-                    host_sync(engine.eval_metrics(
-                        engine.carry_params(carry), logs)),
-                    log_every)
-            params = engine.carry_params(carry)
-    else:
-        with run_span:
-            round_step = _build_round_step(model, fed, cfg)
-            eval_fn = jax.jit(make_eval_fn(model, fed, cfg))
-            lossy = not get_codec(cfg.compression).lossless
-            d = sum(p.size for p in jax.tree.leaves(params))
-            if lossy:
-                carry = (params, jnp.zeros((cfg.n_clients, d), jnp.float32))
-            elif cfg.async_rounds:
-                # async and lossy are mutually exclusive (__post_init__),
-                # so the carry is unambiguous: (params, async state)
-                carry = (params, init_async_state(cfg, (d,)))
-            else:
-                carry = params
-            wrapped = lossy or cfg.async_rounds
-            for i in range(1, cfg.rounds + 1):
-                key, sub = jax.random.split(key)
-                carry, logs = round_step(carry, sub, lrs_all[i - 1])
-                params = carry[0] if wrapped else carry
-                if i % cfg.eval_every == 0 or i == cfg.rounds:
-                    _record_eval(history, i,
-                                 host_sync(eval_fn(params, logs)), log_every)
+    cstats = comm_stats(cfg, d_model, model_shards=engine.model_shards)
+    with telemetry.span(
+            "run_training", n_clients=cfg.n_clients, rounds=cfg.rounds,
+            aggregator=cfg.aggregator, attack=cfg.attack.kind,
+            d=int(d_model), chunk=cfg.client_chunk, pods=cfg.pods,
+            codec=cfg.compression, streaming=bool(cfg.streaming)):
+        params, key, metrics, eval_rounds = engine.run_training(
+            params, key, lrs_all, scen)
+        if metrics is not None:                    # rounds >= 1
+            host = host_sync(metrics)              # THE host sync
+            # the reserved telemetry block rides the same sync and is
+            # drained here — it never enters the history
+            drain_round_telemetry(
+                fed.server, host.pop("_tel", None),
+                uplink_bytes=cstats["uplink_bytes_per_round"])
+            for s, i in enumerate(eval_rounds):
+                _record_eval(history, i,
+                             {k: v[s] for k, v in host.items()},
+                             log_every)
 
     history["final_acc"] = history["acc"][-1] if history["acc"] else float("nan")
     history["params"] = params
     # why a run fell off the streaming path (None when it did not) — on
     # the history, not just the engine instance, so sweep cells and saved
     # histories keep the reason (ISSUE 8 satellite)
-    history["streaming_fallback"] = engine.streaming_fallback \
-        if engine is not None else (
-            fallback_reason(cfg.aggregator)
-            if getattr(cfg, "streaming", False)
-            and get_streaming(cfg.aggregator) is None else None)
+    history["streaming_fallback"] = engine.streaming_fallback
     history.update(cstats)
     return history
 

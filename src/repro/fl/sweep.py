@@ -241,7 +241,7 @@ def execute_sweep(model, fed, spec: SweepSpec,
                             **compiles.snapshot())
             # THE host sync, one per group — looked up through the module
             # so a counter wrapped around simulator.host_sync
-            # (dispatch_bench style) sees sweep syncs too
+            # (tests/test_dispatch_eval.py style) sees sweep syncs too
             host = _sim.host_sync(metrics)
             tel_host = host.pop("_tel", None)     # (G, R, ...) leaves
             for g, (idx, _cell) in enumerate(members):

@@ -76,8 +76,8 @@ def dryrun_one(arch_id: str, shape_name: str, multi_pod: bool,
     if shape.kind == "train":
         specs, _ = train_inputs(cfg, shape, mesh)
         step = make_fl_round_step(
-            cfg, mesh, DiverseFLConfig(), donate=False,
-            compression="bf16" if opt else "f32")
+            cfg, mesh, DiverseFLConfig(),
+            donate=False, compression="bf16" if opt else "f32")
         lowered = step.lower(params, specs)
     elif shape.kind == "prefill":
         prefill = make_prefill(cfg, mesh)

@@ -221,39 +221,6 @@ def shard_lanes(x):
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
-def put_clients_by_shard(build_fn, shape, axis: int = 0,
-                         mesh: Optional[Mesh] = None):
-    """Assemble a client-stacked array **one shard at a time**.
-
-    ``build_fn(lo, hi)`` produces rows ``[lo, hi)`` of client axis
-    ``axis`` (full size on every other dim).  Each shard of the client
-    sharding is built independently, placed directly on its device, and
-    the global array is assembled with
-    ``jax.make_array_from_single_device_arrays`` — no single host
-    buffer ever holds the full ``shape`` stack, which is what lets a
-    multi-pod federation stage per-pod batch stacks whose *union*
-    exceeds one host's memory (data/pipeline.py, DESIGN.md §9).
-
-    Degrades to ``client_put(build_fn(0, C))`` — one full host build —
-    without a mesh or when the client axis does not tile it."""
-    mesh = mesh if mesh is not None else get_mesh()
-    C = shape[axis]
-    sharding = client_sharding(len(shape), axis, mesh)
-    if sharding is None or C % _client_axis_size(mesh) != 0:
-        return client_put(build_fn(0, C), axis)
-    arrays, built = [], {}   # model-axis replicas share one build
-    for dev, idx in sharding.addressable_devices_indices_map(
-            tuple(shape)).items():
-        sl = idx[axis]
-        lo = 0 if sl.start is None else int(sl.start)
-        hi = C if sl.stop is None else int(sl.stop)
-        if (lo, hi) not in built:
-            built[(lo, hi)] = build_fn(lo, hi)
-        arrays.append(jax.device_put(built[(lo, hi)], dev))
-    return jax.make_array_from_single_device_arrays(
-        tuple(shape), sharding, arrays)
-
-
 def shard_clients(x, axis: int = 0):
     """Constrain dim ``axis`` of ``x`` over the data axes (traced code).
 
@@ -270,25 +237,13 @@ def shard_clients(x, axis: int = 0):
         x, NamedSharding(mesh, client_spec(x.ndim, axis, mesh)))
 
 
-def client_put(x, axis: int = 0):
-    """Place a host-built client-stacked array with the client sharding
-    (eager twin of :func:`shard_clients`, for per-segment batch stacks)."""
-    mesh = get_mesh()
-    if mesh is None:
-        return x
-    if x.shape[axis] % _client_axis_size(mesh) != 0:
-        return x
-    s = client_sharding(x.ndim, axis, mesh)
-    return x if s is None else jax.device_put(x, s)
-
-
 def sweep_put(tree):
     """Place a sweep group's stacked operands (leading *scenario* axis on
     every leaf) over the mesh's data axes — one batch of runs per data
     coordinate, the sweep engine's placement contract (fl/sweep.py,
     DESIGN.md §8).
 
-    The scenario axis reuses the client-axis machinery with ``axis=0``:
+    The scenario axis reuses the client sharding with ``axis=0``:
     independent runs are embarrassingly parallel, so they occupy the
     same mesh axes a single run's client axis would.  Degrades per-leaf
     to a no-op without a mesh, without data axes, or when the group
@@ -299,7 +254,16 @@ def sweep_put(tree):
     does not tile the mesh, so placing the scenario axis here is what
     decides the layout; pick group sizes divisible by
     :func:`data_shard_count` to keep cells device-aligned."""
-    return jax.tree.map(lambda x: client_put(x, axis=0), tree)
+    mesh = get_mesh()
+    if mesh is None:
+        return tree
+
+    def put(x):
+        if x.shape[0] % _client_axis_size(mesh) != 0:
+            return x
+        s = client_sharding(x.ndim, 0, mesh)
+        return x if s is None else jax.device_put(x, s)
+    return jax.tree.map(put, tree)
 
 
 # ----------------------------------------------------------------------
@@ -461,7 +425,7 @@ def flatten_updates_sharded(updates):
     raveled, zero-padded to a multiple of ``ms``, folded into ``ms``
     rows, and the concat runs shard-local while the per-leaf reshape
     lowers to one slice per shard.  Peak extra memory is one leaf, not
-    D (DESIGN.md §12, benchmarks/model_fl_bench).
+    D (DESIGN.md §12).
 
     Row assignment is **tiling-aligned**: a leaf whose partition-table
     spec shards dim ``k`` over ``model`` is split along dim ``k`` into

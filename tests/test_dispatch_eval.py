@@ -4,21 +4,21 @@ Contracts:
 
   * **in-scan eval == host-loop eval, bitwise** — the one-dispatch path
     (eval folded into the outer scan, one host sync) reproduces the
-    legacy per-segment host-eval loop and the seed per-round loop
-    bit-for-bit: params, every metric history, every eval round index —
-    including partial participation, backdoor attacks (main-task +
-    backdoor accuracy), streaming aggregation, and a final partial
-    segment when ``rounds % eval_every != 0``.
+    per-round reference loop with its eval on the host
+    (``conftest.run_seed_loop``) bit-for-bit: params, every metric
+    history, every eval round index — including partial participation,
+    backdoor attacks (main-task + backdoor accuracy), streaming
+    aggregation, and a final partial segment when
+    ``rounds % eval_every != 0``.
   * **metrics are jittable where-masked reductions** — no boolean
     indexing, no ``float()`` casts: the same function jits, returns
     device scalars, and matches a NumPy reference computed with the
     seed's dynamic-shape indexing semantics.
   * **the host sync is one, and counted** — every device→host
     materialization flows through ``repro.fl.simulator.host_sync``; a
-    multi-segment run syncs exactly once on the one-dispatch path and
-    once per segment on the legacy path.
-  * **the donate knob threads** — FLConfig.donate → RoundEngine,
-    tri-state (None = backend auto).
+    multi-segment run syncs exactly once.
+  * **donation follows the backend** — the engine donates its carry
+    wherever the backend supports it, and nowhere else.
 """
 import jax
 import jax.numpy as jnp
@@ -60,11 +60,11 @@ def _cfg(**kw):
     return FLConfig(**kw)
 
 
-def _train(fed_data, cfg, **kw):
+def _train(fed_data, cfg, loop=run_federated_training):
     data, tx, ty = fed_data
     model = softmax_regression(input_dim=DIM, n_classes=N_CLASSES)
     fed = Federation.create(model, data, tx, ty, cfg, jax.random.PRNGKey(2))
-    return run_federated_training(model, fed, cfg, inv_sqrt_lr(0.05), **kw)
+    return loop(model, fed, cfg, inv_sqrt_lr(0.05))
 
 
 def _flat(params):
@@ -82,7 +82,7 @@ def _assert_histories_bitwise(a, b):
 
 
 # ----------------------------------------------------------------------
-# in-scan eval == host-loop eval == seed loop: bitwise
+# in-scan eval == host-loop eval on the per-round reference: bitwise
 # ----------------------------------------------------------------------
 
 @pytest.mark.parametrize("kw", [
@@ -94,17 +94,17 @@ def _assert_histories_bitwise(a, b):
     {"streaming": True, "client_chunk": 8, "rounds": 4,
      "attack": AttackConfig(kind="gaussian")},             # streaming rounds
 ])
-def test_in_scan_eval_matches_host_loop_bitwise(fed_data, kw):
+def test_in_scan_eval_matches_host_loop_bitwise(fed_data, seed_loop, kw):
     cfg = _cfg(**kw)
     h_dev = _train(fed_data, cfg)
-    h_host = _train(fed_data, cfg, host_eval=True)
+    h_host = _train(fed_data, cfg, loop=seed_loop)
     _assert_histories_bitwise(h_dev, h_host)
 
 
-def test_in_scan_eval_matches_seed_loop_bitwise(fed_data):
+def test_in_scan_eval_matches_seed_loop_bitwise(fed_data, seed_loop):
     cfg = _cfg(eval_every=2)
     h_dev = _train(fed_data, cfg)
-    h_seed = _train(fed_data, cfg, use_engine=False)
+    h_seed = _train(fed_data, cfg, loop=seed_loop)
     _assert_histories_bitwise(h_dev, h_seed)
 
 
@@ -206,10 +206,10 @@ def test_federation_backdoor_eval_is_cached(fed_data):
 
 
 # ----------------------------------------------------------------------
-# host syncs: one per run (one-dispatch) vs one per segment (legacy)
+# host syncs: one per run
 # ----------------------------------------------------------------------
 
-def _count_syncs(fed_data, cfg, monkeypatch, **kw):
+def _count_syncs(fed_data, cfg, monkeypatch):
     counter = {"n": 0}
     orig = sim.host_sync
 
@@ -218,7 +218,7 @@ def _count_syncs(fed_data, cfg, monkeypatch, **kw):
         return orig(tree)
 
     monkeypatch.setattr(sim, "host_sync", counting)
-    h = _train(fed_data, cfg, **kw)
+    h = _train(fed_data, cfg)
     return counter["n"], h
 
 
@@ -226,8 +226,6 @@ def test_one_dispatch_syncs_once(fed_data, monkeypatch):
     cfg = _cfg(rounds=6, eval_every=2)          # 3 segments
     n_dev, _ = _count_syncs(fed_data, cfg, monkeypatch)
     assert n_dev == 1
-    n_host, _ = _count_syncs(fed_data, cfg, monkeypatch, host_eval=True)
-    assert n_host == 3
 
 
 def test_one_dispatch_under_transfer_guard(fed_data):
@@ -264,23 +262,31 @@ def test_telemetry_keeps_single_sync_under_transfer_guard(fed_data,
 
 
 # ----------------------------------------------------------------------
-# donate knob: FLConfig -> RoundEngine, tri-state
+# donation: follows the backend
 # ----------------------------------------------------------------------
 
-def test_donate_knob_threads_through(fed_data):
+def test_donate_knob_threads_through(fed_data, monkeypatch):
+    """Donation follows the backend: on wherever it is supported, off
+    on XLA:CPU; the lowered program asks for it exactly then."""
     data, tx, ty = fed_data
     model = softmax_regression(input_dim=DIM, n_classes=N_CLASSES)
+    cfg = _cfg()
+    fed = Federation.create(model, data, tx, ty, cfg, jax.random.PRNGKey(2))
+    params = model.init(jax.random.PRNGKey(1))
+    lrs = jnp.full((cfg.rounds,), 0.05, jnp.float32)
 
-    def engine(**kw):
-        cfg = _cfg(**kw)
-        fed = Federation.create(model, data, tx, ty, cfg,
-                                jax.random.PRNGKey(2))
-        return RoundEngine(model, fed, cfg)
+    def donated(backend):
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        engine = RoundEngine(model, fed, cfg)
+        monkeypatch.undo()
+        text = engine.lower_training(params, jax.random.PRNGKey(0),
+                                     lrs).as_text()
+        return engine.donate, "tf.aliasing_output" in text
 
-    auto = jax.default_backend() != "cpu"
-    assert engine().donate is auto              # None -> backend auto
-    assert engine(donate=True).donate is True   # forced on (measurement)
-    assert engine(donate=False).donate is False
+    assert donated("cpu") == (False, False)
+    assert donated("tpu") == (True, True)
+    assert RoundEngine(model, fed, cfg).donate is (
+        jax.default_backend() != "cpu")
 
 
 def test_lower_training_traces_the_program_run_training_runs(fed_data):
@@ -302,12 +308,3 @@ def test_lower_training_traces_the_program_run_training_runs(fed_data):
         engine.run_training(params, jax.random.PRNGKey(0), lrs)
         assert tc["training"] == 1
     assert "while" in compiled.as_text()
-
-
-def test_donate_forced_on_still_runs(fed_data):
-    """donate=True on CPU compiles and runs (XLA ignores the request);
-    the numbers cannot change."""
-    cfg_on, cfg_off = _cfg(rounds=4, donate=True), _cfg(rounds=4)
-    h_on = _train(fed_data, cfg_on)
-    h_off = _train(fed_data, cfg_off)
-    assert np.array_equal(_flat(h_on["params"]), _flat(h_off["params"]))

@@ -1,11 +1,11 @@
 """RoundEngine / simulator equivalence (ISSUE 2 satellite).
 
 The scan-compiled engine must be a pure compilation strategy, not a new
-algorithm: with ``eval_every=1`` and ``client_chunk=N`` it reproduces
-the seed per-round jitted loop bit-for-bit on fixed seeds, and chunked
+algorithm: it reproduces the per-round jitted reference loop
+(``conftest.run_seed_loop``) bit-for-bit on fixed seeds, and chunked
 execution (``client_chunk < N``) matches unchunked to fp tolerance
-across aggregators.  The segment-stack batch mode and the mesh-sharded
-path must be bit-identical to the inline path.
+across aggregators.  The mesh-sharded path must be bit-identical to the
+meshless one.
 """
 import jax
 import jax.numpy as jnp
@@ -47,22 +47,23 @@ def _flat(params):
         [np.asarray(v).ravel() for v in jax.tree.leaves(params)])
 
 
-def _train(data, tx, ty, cfg, **kw):
+def _train(data, tx, ty, cfg, loop=run_federated_training):
     model = softmax_regression()
     fed = Federation.create(model, data, tx, ty, cfg, jax.random.PRNGKey(2))
-    return run_federated_training(model, fed, cfg, inv_sqrt_lr(0.05), **kw)
+    return loop(model, fed, cfg, inv_sqrt_lr(0.05))
 
 
 # ----------------------------------------------------------------------
-# scan engine vs seed per-round loop: bit-for-bit
+# scan engine vs the per-round reference loop: bit-for-bit
 # ----------------------------------------------------------------------
 
 @pytest.mark.parametrize("eval_every", [1, 3])
-def test_scan_engine_reproduces_seed_loop_bitwise(small_fed, eval_every):
+def test_scan_engine_reproduces_seed_loop_bitwise(small_fed, seed_loop,
+                                                  eval_every):
     data, tx, ty = small_fed
     cfg = _cfg(eval_every=eval_every)
     h_eng = _train(data, tx, ty, cfg)
-    h_seed = _train(data, tx, ty, cfg, use_engine=False)
+    h_seed = _train(data, tx, ty, cfg, loop=seed_loop)
     assert np.array_equal(_flat(h_eng["params"]), _flat(h_seed["params"]))
     assert h_eng["round"] == h_seed["round"]
     assert h_eng["acc"] == h_seed["acc"]
@@ -107,7 +108,7 @@ def test_chunked_vmap_matches_vmap_with_padding():
 
 
 # ----------------------------------------------------------------------
-# batch modes and mesh sharding
+# mesh sharding
 # ----------------------------------------------------------------------
 
 def _engine_segment(model, fed, cfg, **kw):
@@ -117,26 +118,14 @@ def _engine_segment(model, fed, cfg, **kw):
     return engine.run_segment(params0, jax.random.PRNGKey(cfg.seed), lrs)
 
 
-def test_segment_batch_mode_is_bitwise(small_fed):
-    """Per-segment minibatch stacks (data pipeline) == in-body sampling."""
-    data, tx, ty = small_fed
-    cfg = _cfg()
-    model = softmax_regression()
-    fed = Federation.create(model, data, tx, ty, cfg, jax.random.PRNGKey(2))
-    p_in, k_in, _ = _engine_segment(model, fed, cfg, batch_mode="inline")
-    p_seg, k_seg, _ = _engine_segment(model, fed, cfg, batch_mode="segment")
-    assert np.array_equal(_flat(p_in), _flat(p_seg))
-    assert np.array_equal(np.asarray(k_in), np.asarray(k_seg))
-
-
 def test_mesh_sharded_engine_is_bitwise(small_fed):
-    """An active ("data","model") mesh (client-axis NamedShardings +
-    segment batch stacks) must not change the numbers."""
+    """An active ("data","model") mesh (client-axis NamedShardings)
+    must not change the numbers."""
     data, tx, ty = small_fed
     cfg = _cfg(client_chunk=8)
     model = softmax_regression()
     fed = Federation.create(model, data, tx, ty, cfg, jax.random.PRNGKey(2))
-    p_ref, _, _ = _engine_segment(model, fed, cfg, batch_mode="inline")
+    p_ref, _, _ = _engine_segment(model, fed, cfg)
     mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
     p_mesh, _, logs = _engine_segment(model, fed, cfg, mesh=mesh)
     assert np.array_equal(_flat(p_ref), _flat(p_mesh))
@@ -156,12 +145,12 @@ def test_n_selected_uses_ceil():
     assert FLConfig(n_clients=10, participation=0.0).n_selected == 1
 
 
-def test_engine_partial_participation_matches_seed(small_fed):
+def test_engine_partial_participation_matches_seed(small_fed, seed_loop):
     """Selection RNG (ks subkey) is part of the bit-for-bit contract."""
     data, tx, ty = small_fed
     cfg = _cfg(participation=0.5, rounds=4)
     h_eng = _train(data, tx, ty, cfg)
-    h_seed = _train(data, tx, ty, cfg, use_engine=False)
+    h_seed = _train(data, tx, ty, cfg, loop=seed_loop)
     assert np.array_equal(_flat(h_eng["params"]), _flat(h_seed["params"]))
 
 

@@ -1,6 +1,6 @@
 """Unit tests for launch/hlo.py — the compiled-HLO collective census.
 
-The dryrun harness and benchmarks/comm_bench.py both trust this parser
+The dryrun harness and benchmarks/roofline.py both trust this parser
 to turn compiled module text into collective byte counts; these tests
 pin it against a hand-written HLO fixture (every dtype, tuple-result
 async starts, metadata lines that must NOT match) so a regex regression

@@ -272,7 +272,7 @@ def test_mesh_sharded_fold_bitwise_subprocess():
                        **kw)
         fed = Federation.create(model, data, tx, ty, cfg,
                                 jax.random.PRNGKey(2))
-        eng = RoundEngine(model, fed, cfg, mesh=mesh, batch_mode="segment")
+        eng = RoundEngine(model, fed, cfg, mesh=mesh)
         params = model.init(jax.random.PRNGKey(1))
         lrs = [float(inv_sqrt_lr(0.05)(r)) for r in (1, 2)]
         p, _, logs = eng.run_segment(params, jax.random.PRNGKey(0), lrs)

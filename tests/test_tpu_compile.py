@@ -21,7 +21,7 @@ from repro.kernels import masked_agg, robust_agg, similarity
 
 V5E_HBM_BYTES = 16 * 1024 ** 3
 
-# fl-llm-100m (benchmarks/model_fl_bench.FULL_MODEL): one streaming block
+# fl-llm-100m (chip_smoke.decoder_model): one streaming block
 # of client_chunk=1 rows over the flat decoder
 DECODER_D = 100_369_280
 
