@@ -48,8 +48,10 @@ logs) for the masked-mean family, at any chunk size, with any
 participation.  Padding rows contribute exact ±0.0 (weight 0) and a
 trailing ``x + 0.0`` cannot change a float's magnitude.  With
 ``use_kernel_agg`` the fold instead accumulates per *block* through the
-streaming Pallas kernel (kernels/masked_agg.masked_agg_update_kernel) —
-one HBM pass per block into a donated (D,) accumulator; block-level
+streaming Pallas kernel (kernels/masked_agg.masked_agg_fold_kernel) —
+one HBM pass per block into an accumulator that keeps, for the whole
+sweep, the kernel's own 2-D layout (``fold_layout``: the
+lane-dense (D/128, 128) view, else the (1, D) row); block-level
 association trades the bitwise guarantee for fp-tolerance parity.
 """
 from __future__ import annotations
@@ -65,6 +67,7 @@ from ..core.diversefl import criterion_logs, diversefl_mask
 from ..sharding import (data_shard_count, model_shard_count,
                         pod_data_counts, shard_clients,
                         shard_flat, shard_lanes)
+from . import telemetry
 from .chunking import (block_valid, group_blocks, group_blocks_2d,
                        pad_to_blocks, resolve_pods, resolve_shards, unblock)
 from .server import _REGISTRY as _DENSE_REGISTRY
@@ -218,6 +221,17 @@ def weighted_mean_rule(weight_fn: Callable, *, floor: float = 1.0,
     associative, and commutative up to fp rounding.  Rows flagged
     invalid (padding) get weight exactly 0.0.
 
+    **The numerator's layout.**  On the kernel block fold of the flat
+    layout (``use_kernel``, a raw or dense-payload stream) the numerator
+    is, from ``init`` to ``finalize``, the 2-D accumulator the Pallas
+    fold reads and writes: ``kernels/masked_agg.fold_layout``'s
+    lane-dense (D/128, 128) view where 128 divides D, else the (1, D)
+    row.  ``update`` and ``merge`` work in it, and ``finalize`` hands a
+    flat (D,) delta on, once a round.  Each trace of ``init`` records a
+    ``fold_layout`` event (rows, lanes, ``view``) on the flight
+    recorder.  The row fold, the int8 fold and the model-sharded layout
+    keep their flat (D,) or blocked (ms, L) numerator.
+
     **Model-sharded D** (DESIGN.md §12): on a client x model mesh the
     (D,) numerator is constrained over the ``model`` axis at ``init``
     and ``finalize``, so the fold's ``s + u_i * a_i`` is a *per-shard
@@ -240,6 +254,9 @@ def weighted_mean_rule(weight_fn: Callable, *, floor: float = 1.0,
     # encode non-finite payloads, and the decode path is pinned
     # bitwise against the dense reference decoder.
     guard = codec is None
+    # the Pallas fold's own accumulator layout (see the docstring); the
+    # int8 fold (kernels/dequant_fold.py) keeps a flat (D,) numerator
+    kernel_layout = use_kernel and (codec is None or codec.qblock is None)
 
     def _screen(ud):
         """(sanitized update, finite-row bits or None).
@@ -274,7 +291,15 @@ def weighted_mean_rule(weight_fn: Callable, *, floor: float = 1.0,
         # multiply-add shard-local (no-op on a trivial model axis).
         # ``d`` is the flat length (classic layout) or the blocked
         # (ms, L) shape tuple (model-sharded layout).
-        shape = d if isinstance(d, tuple) else (d,)
+        if isinstance(d, tuple):
+            shape = d
+        elif kernel_layout:
+            from ..kernels.masked_agg import fold_layout
+            shape = fold_layout(d)
+            telemetry.event("fold_layout", rows=shape[0], lanes=shape[1],
+                            view=shape[1] % 128 == 0)
+        else:
+            shape = (d,)
         return (shard_flat(jnp.zeros(shape, jnp.float32)),
                 jnp.zeros((), jnp.float32))
 
@@ -292,7 +317,10 @@ def weighted_mean_rule(weight_fn: Callable, *, floor: float = 1.0,
             a, b = a * ff, b * ff
             logs = dict(logs, nonfinite=~fin)
         a, b = _valid(a, b, ctx)
-        return (s + ud.astype(jnp.float32) * a, n + b), logs
+        # one row into the numerator's layout (a no-op but on the kernel
+        # fold's 2-D accumulator)
+        return (s + ud.astype(jnp.float32).reshape(s.shape) * a,
+                n + b), logs
 
     def merge(x, y):
         return jax.tree.map(jnp.add, x, y)
@@ -300,6 +328,10 @@ def weighted_mean_rule(weight_fn: Callable, *, floor: float = 1.0,
     @jax.named_scope("step5_fold")
     def finalize(state):
         s, n = state
+        if flat_ndim() == 1:
+            # the kernel fold's 2-D accumulator as the flat vector it
+            # tiles (a bitcast on the TPU), divided once
+            s = s.reshape(-1)
         # the round delta inherits the numerator's model sharding — the
         # division is elementwise, so no gather happens here either
         return shard_flat(s / jnp.maximum(n, jnp.float32(floor))), {}

@@ -46,11 +46,17 @@ def masked_aggregate(u, mask, chunk: int = _ma.DEFAULT_CHUNK):
 
 @functools.partial(jax.jit, static_argnames=("chunk",))
 def masked_agg_update(u, w, acc, chunk: int = _ma.DEFAULT_CHUNK):
-    """Streaming accumulate: (n, D) block + (n,) weights + (D,) carried
-    partial -> (D,) ``acc + sum_i w_i * u_i`` in one HBM pass over u.
-    The Pallas leg of the streaming AggState ``update_block`` — the
-    1/|kept| normalization happens once at ``finalize``, not here."""
+    """Streaming accumulate: (n, D) block + (n,) weights + carried
+    partial -> ``acc + sum_i w_i * u_i`` in one HBM pass over u, in
+    acc's shape: a (D,) vector, or the 2-D accumulator of
+    ``masked_agg.fold_layout(D)``, which the kernel reads and writes
+    with no relayout.  The Pallas leg of the streaming AggState
+    ``update_block`` — the 1/|kept| normalization happens once at
+    ``finalize``, not here."""
     with jax.named_scope("step5_fold"):
+        if acc.ndim == 2:
+            return _ma.masked_agg_fold_kernel(u, w, acc, chunk=chunk,
+                                              interpret=_interpret())
         return _ma.masked_agg_update_kernel(u, w, acc, chunk=chunk,
                                             interpret=_interpret())
 

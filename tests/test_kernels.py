@@ -152,6 +152,31 @@ def test_masked_agg_leaf_matches_row_kernel(shape, block_bytes):
                                rtol=1e-5, atol=1e-6)
 
 
+# the (D/128, 128) view and the (1, D) row of fold_layout; blocks of 1,
+# 3 and 40 clients (two client tiles, the second part full)
+@pytest.mark.parametrize("block_bytes", LEAF_BLOCKS)
+@pytest.mark.parametrize("c,d,dtype", [
+    (1, 128 * 37, jnp.float32), (3, 128 * 37, jnp.float32),
+    (40, 128 * 37, jnp.float32), (3, 128 * 37, jnp.bfloat16),
+    (3, 1000, jnp.float32)])
+def test_masked_agg_fold_matches_flat_fold(c, d, dtype, block_bytes):
+    """The streaming fold into its own 2-D accumulator equals the fold
+    into a flat (D,) one, bit for bit: each element is acc + w·u for a
+    single client, and the same sum over each client tile otherwise."""
+    from repro.kernels import masked_agg
+    rng = np.random.default_rng(c + d)
+    u = jnp.asarray(rng.normal(size=(c, d))).astype(dtype)
+    w = jnp.asarray(rng.uniform(size=c).astype(np.float32))
+    acc = jnp.asarray(rng.normal(size=d).astype(np.float32))
+    want = masked_agg.masked_agg_update_kernel(u, w, acc, interpret=True)
+    got = masked_agg.masked_agg_fold_kernel(
+        u, w, acc.reshape(masked_agg.fold_layout(d)), interpret=True,
+        **_blocks(block_bytes))
+    assert got.shape == masked_agg.fold_layout(d)
+    np.testing.assert_array_equal(np.asarray(got).reshape(-1),
+                                  np.asarray(want))
+
+
 def test_masked_agg_leaf_empty_mask_yields_zero():
     u = jnp.ones((4, 27, 64))
     got = ops.masked_aggregate_leaves({"w": u, "b": u[:, 0]},

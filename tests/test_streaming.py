@@ -31,7 +31,9 @@ from repro.data.partition import partition_sorted_shards
 from repro.fl import (FLConfig, Federation, RoundEngine, chunked_vmap,
                       fallback_reason, get_streaming,
                       run_federated_training, softmax_regression,
-                      streaming_rules)
+                      streaming_rules, telemetry)
+from repro.kernels import ops as kops
+from repro.kernels.masked_agg import fold_layout
 from repro.fl.server import KERNEL_AGG_RULES, AggregationContext, aggregate
 from repro.fl.streaming import NON_STREAMING, stream_aggregate
 from repro.optim import inv_sqrt_lr
@@ -129,6 +131,107 @@ def test_streaming_kernel_path(fed_data):
     np.testing.assert_allclose(_flat(h_kern["params"]),
                                _flat(h_dense["params"]),
                                rtol=1e-4, atol=1e-6)
+
+
+# the kernel fold's accumulator layout (kernels/masked_agg.fold_layout):
+# D a multiple of 512, of 128 only, and of neither (the (1, D) row)
+FOLD_WIDTHS = {"d512": 512 * 9, "d128": 128 * 13, "row": 1000}
+
+
+def _sweep_rows(d):
+    """Updates and guides of 10 clients: client 4 sends a non-finite
+    update and the guides of clients 1 and 7 point away."""
+    rng = np.random.default_rng(d)
+    U = rng.normal(size=(10, d)).astype(np.float32)
+    G = U + 0.3 * rng.normal(size=U.shape).astype(np.float32)
+    G[[1, 7]] *= -1
+    U[4, 5] = np.inf
+    return U, G
+
+
+def _diversefl_sweep(d, chunk, merge, kernel):
+    """The streaming diversefl rule over ``_sweep_rows`` in blocks of
+    ``chunk``.  ``merge``: one sweep (``plain``), two shard groups
+    (``shards``), or two landed async rows merged before finalize
+    (``async``).  Returns (delta, client logs, fold_layout events)."""
+    U, G = map(jnp.asarray, _sweep_rows(d))
+    byz = jnp.zeros((10,), bool)
+    rule = get_streaming("diversefl").bind(AggregationContext(
+        byz_mask=byz, use_kernel_agg=kernel))
+
+    def block_fn(blk, valid):
+        u, g, b = blk
+        return u, {"guide": g, "byz": b}
+
+    with telemetry.recording() as rec:
+        extra = None
+        if merge == "async":
+            ctx = {"guide": G[:2], "byz": byz[:2],
+                   "valid": jnp.ones((2,), jnp.float32)}
+            extra, _ = jax.lax.scan(
+                lambda st, r: rule.update(st, r[0], r[1]), rule.init(d),
+                (U[:2] * 0.5, ctx))
+        delta, _, logs = stream_aggregate(
+            rule, block_fn, (U, G, byz), chunk, d=d, prefer_block=kernel,
+            shards=2 if merge == "shards" else 1, extra_state=extra)
+    events = [{k: r[k] for k in ("rows", "lanes", "view")}
+              for r in rec.records if r.get("kind") == "fold_layout"]
+    return delta, logs, events
+
+
+def _todays_kernel_fold(d):
+    """The chunk-1 sweep as the flat (D,) accumulator folded it through
+    ``ops.masked_agg_update`` one client at a time."""
+    U, G = _sweep_rows(d)
+    rule = get_streaming("diversefl").bind(AggregationContext())
+    s, n = jnp.zeros((d,), jnp.float32), jnp.float32(0.0)
+    for i in range(10):
+        a, b, _ = rule.weights(jnp.asarray(U[i:i + 1]), {
+            "guide": jnp.asarray(G[i:i + 1]), "byz": jnp.zeros((1,), bool),
+            "valid": jnp.ones((1,), jnp.float32)})
+        u = np.where(np.isfinite(U[i]).all(), U[i], 0.0)[None]
+        s = kops.masked_agg_update(jnp.asarray(u), a, s)
+        n = n + jnp.sum(b)
+    return s / jnp.maximum(n, 1.0)
+
+
+@pytest.mark.parametrize("merge", ["plain", "shards", "async"])
+@pytest.mark.parametrize("chunk", [1, 3])
+@pytest.mark.parametrize("width", sorted(FOLD_WIDTHS))
+def test_kernel_fold_layout_matches_row_fold(width, chunk, merge):
+    """With ``use_kernel_agg`` the numerator lives in the Pallas fold's
+    own 2-D layout for the whole sweep (init, update_block, update,
+    merge, finalize) and the fold matches the row fold: bit for bit at
+    client_chunk 1 (each element is still acc + w·u, as the flat
+    accumulator's kernel folded it), to the kernel path's tolerance in
+    blocks of 3 with padding rows.  Keep mask and C1/C2 logs are the
+    row fold's bits, and the non-finite client gets weight 0 and leaves
+    the delta finite."""
+    d = FOLD_WIDTHS[width]
+    delta, logs, events = _diversefl_sweep(d, chunk, merge, kernel=True)
+    want, want_logs, row_events = _diversefl_sweep(d, chunk, merge,
+                                                   kernel=False)
+    rows, lanes = fold_layout(d)
+    assert (lanes % 128 == 0) == (width != "row")
+    assert events and all(e == {"rows": rows, "lanes": lanes,
+                                "view": width != "row"} for e in events)
+    assert not row_events
+    assert delta.shape == want.shape == (d,)
+    assert np.isfinite(np.asarray(delta)).all()
+    assert set(logs) == set(want_logs)
+    for k in logs:
+        np.testing.assert_array_equal(np.asarray(logs[k]),
+                                      np.asarray(want_logs[k]), err_msg=k)
+    assert bool(logs["nonfinite"][4]) and not bool(logs["mask"][4])
+    assert not bool(logs["mask"][1]) and not bool(logs["mask"][7])
+    if chunk == 1:
+        np.testing.assert_array_equal(np.asarray(delta), np.asarray(want))
+        if merge == "plain":
+            np.testing.assert_array_equal(np.asarray(delta),
+                                          np.asarray(_todays_kernel_fold(d)))
+    else:
+        np.testing.assert_allclose(np.asarray(delta), np.asarray(want),
+                                   rtol=1e-4, atol=1e-6)
 
 
 # ----------------------------------------------------------------------

@@ -64,6 +64,15 @@ def _fold(n, d):
                 ((d,), jnp.float32)]
 
 
+def _fold_view(d, c=1, dtype=jnp.float32):
+    """The streaming fold of a block of ``c`` clients into its own
+    accumulator layout."""
+    def fn(u, w, acc):
+        return masked_agg.masked_agg_fold_kernel(u, w, acc)
+    return fn, [((c, d), dtype), ((c,), jnp.float32),
+                (masked_agg.fold_layout(d), jnp.float32)]
+
+
 def _flash(b, h, k, s, dh, window=None):
     def fn(q, kk, v):
         return flash_attention.flash_attention_kernel(q, kk, v,
@@ -97,6 +106,15 @@ CASES = {
         masked_agg.masked_agg_kernel,
         [((23, dv), jnp.float32), ((23,), jnp.bool_)]),
     "masked_agg_update_decoder_n1": lambda dv: _fold(1, DECODER_D),
+    # the silo cells' streaming fold: danube's and V2-Lite's D
+    "masked_agg_fold_danube_n1": lambda dv: _fold_view(441_735_680),
+    "masked_agg_fold_v2_lite_n1": lambda dv: _fold_view(535_060_992),
+    # a dense bf16 payload in blocks of 3, and the (1, D) row of a D
+    # that 128 does not divide
+    "masked_agg_fold_bf16_n3": lambda dv: _fold_view(DECODER_D, 3,
+                                                     jnp.bfloat16),
+    "masked_agg_fold_row_n3": lambda dv: _fold_view((1 << 20) + 3, 3),
+    "masked_agg_fold_n40": lambda dv: _fold_view(1 << 20, 40),
     # a cross-device block: the client axis is tiled, VMEM stays bounded
     "masked_agg_update_n1024": lambda dv: _fold(1024, (1 << 20) + 3),
     "dequant_fold_n1024": lambda dv: (
@@ -243,3 +261,74 @@ def test_silo_training_step_has_no_score_matrix(mixer, one_chip,
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) <= V5E_HBM_BYTES
+
+
+def _instructions(comp: str):
+    """(name, opcode, output shape text) of each instruction of one HLO
+    computation."""
+    out = []
+    for line in comp.split("\n")[1:]:
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\(",
+                     line)
+        if m:
+            out.append((m.group(1), m.group(3), m.group(2)))
+    return out
+
+
+def test_silo_round_folds_without_relayout(one_chip, monkeypatch):
+    """A silo cell's streaming round, cut to one layer at small widths
+    (bf16, 4 silos, client_chunk 1, the kernel fold), compiled for the
+    chip: the block sweep folds each client with one Pallas call into
+    the carried accumulator, and no op of the sweep slices, broadcasts,
+    reshapes or reduces a float32 array of D elements (the relayouts of
+    a flat (D,) carry around the kernel's 2-D block).  The one D-wide
+    copy allowed is the carry's, in its own layout, into the call."""
+    from repro.core.attacks import AttackConfig
+    from repro.fl import FLConfig, RoundEngine, make_zoo_federation, \
+        telemetry, zoo_model
+    from repro.models import ModelConfig
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mc = ModelConfig(name="silo-cut", n_layers=1, d_model=512, n_heads=4,
+                     n_kv_heads=2, head_dim=128, d_ff=1024,
+                     vocab_size=2048, layout=(("swa", "mlp"),),
+                     window=4096, activation="swiglu", dtype="bfloat16",
+                     param_dtype="bfloat16")
+    model = zoo_model(mc, seq_len=255)
+    cfg = FLConfig(n_clients=4, f=1, rounds=2, local_steps=1, batch_size=2,
+                   l2=0.0, aggregator="diversefl",
+                   attack=AttackConfig(kind="sign_flip"), sample_frac=0.5,
+                   streaming=True, client_chunk=1, use_kernel_agg=True,
+                   eval_every=2)
+    fed = make_zoo_federation(model, cfg, jax.random.PRNGKey(0),
+                              per_client=4, n_test=2)
+    engine = RoundEngine(model, fed, cfg)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(model.init, jax.random.PRNGKey(1)))
+    d = sum(p.size for p in jax.tree.leaves(params))
+    assert d % 512 == 0 and 1e6 < d < 1e7
+    with telemetry.recording() as rec:
+        text = engine.lower_training(params, jax.random.PRNGKey(3),
+                                     [0.1, 0.1]).compile().as_text()
+    rows, lanes = masked_agg.fold_layout(d)
+    assert [(r["rows"], r["lanes"], r["view"]) for r in rec.records
+            if r.get("kind") == "fold_layout"] == [(rows, lanes, True)]
+    sweep = [c for c in text.split("\n\n")
+             if re.search(r"%masked_agg[\w.]* = \S+ custom-call\(", c)]
+    assert len(sweep) == 1
+    ops = _instructions(sweep[0])
+    assert len([n for n, op, _ in ops if op == "custom-call"
+                and n.startswith("masked_agg")]) == 1
+    wide = re.compile(r"f32\[([\d,]+)\]")
+    copies = []
+    for name, op, shape in ops:
+        m = wide.match(shape)
+        if m is None or math.prod(int(x) for x in m.group(1).split(",")) != d:
+            continue
+        kind = op if op != "fusion" else name
+        assert not any(k in kind for k in ("dynamic-slice", "broadcast",
+                                           "reduce", "reshape")), \
+            (name, op, shape)
+        if "copy" in kind:
+            copies.append(m.group(1))
+    assert copies in ([], [f"{rows},{lanes}"]), copies
